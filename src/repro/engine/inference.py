@@ -70,7 +70,6 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ..compiler.schedule import CoreSchedule
@@ -352,13 +351,13 @@ def _multicore_apply(el: EngineLayer, s2: jax.Array, v2: jax.Array,
         # mesh device, so idle cores ride along with zero weights (they are
         # idle silicon either way).
         v_cores = jnp.stack([pad_slice(lo, hi) for lo, hi in el.core_slices])
-        fn = shard_map(
+        fn = jax.shard_map(
             lambda sp, *blocks: jax.vmap(
                 lambda *bs: core_update(sp, bs))(*blocks),
             mesh=_cores_mesh(n_cores),
             in_specs=(P(),) + (P("cores"),) * (len(per_core_ops) + 1),
             out_specs=(P("cores"), P("cores")),
-            check_rep=False,
+            check_vma=False,
         )
         v_next, s = fn(s2, *per_core_ops, v_cores)
         row = {c: c for c in range(n_cores)}

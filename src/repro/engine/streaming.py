@@ -301,15 +301,18 @@ class StreamSessionManager:
             prev_cycles = self.slot_cycles.copy()
             prev_energy = self.slot_energy_uj.copy()
 
+        # Upload straight to the session's device (None: the default one).
+        ev_dev = jax.device_put(ev, self.device)
+
         if self._tracer:
             with self._tracer.span("run_chunk", cat="session",
                                    tick=self.ticks, slots=len(valid)):
-                self.state, out = self._step(self.state, jnp.asarray(ev))
+                self.state, out = self._step(self.state, ev_dev)
                 # Sync inside the span so it measures the device step, not
                 # just async dispatch (we host-transfer right below anyway).
                 out = jax.block_until_ready(out)
         else:
-            self.state, out = self._step(self.state, jnp.asarray(ev))
+            self.state, out = self._step(self.state, ev_dev)
         self.ticks += 1
 
         readouts = np.asarray(out.readouts)          # (chunk_T, capacity, ...)
@@ -506,6 +509,8 @@ class StreamSessionManager:
             out_counts=jnp.asarray(es["out_counts"], jnp.int32),
             in_counts=jnp.asarray(es["in_counts"], jnp.int32),
         )
+        if self.device is not None:
+            self.state = jax.device_put(self.state, self.device)
         self.active = [bool(a) for a in np.asarray(table["active"])]
         self.ended = [bool(e) for e in np.asarray(table["ended"])]
         self.slot_timesteps = np.asarray(table["timesteps"], np.int64).copy()

@@ -101,15 +101,21 @@ def _default_candidates(rows: int, fan_in: int, channels: int,
     candidates clip to the next power-of-two cover of each dimension; the
     T_blk axis sweeps 1 (the scan-equivalent schedule) up to the full
     sample depth.
+
+    Only tilings the TPU compiler accepts are offered.  ``bn`` and ``bk``
+    are lane dimensions (of the weights/Vmem and of the spikes): each must
+    be a multiple of 128 or cover the whole dimension in one block.
+    ``bm`` and ``bk`` are also int8 sublane dimensions, which every option
+    here satisfies as a multiple of 32.
     """
-    def cover(dim, opts):
-        kept = [o for o in opts if o < 2 * dim] or [opts[0]]
-        return kept
+    def cover(dim, opts, lane=False):
+        legal = [o for o in opts if not lane or o % 128 == 0 or o >= dim]
+        return [o for o in legal if o < 2 * dim] or legal[:1]
 
     blocks = []
     for bm in cover(rows, (32, 128)):
-        for bn in cover(channels, (32, 128)):
-            for bk in cover(fan_in, (32, 128)):
+        for bn in cover(channels, (32, 128), lane=True):
+            for bk in cover(fan_in, (32, 128), lane=True):
                 blocks.append((bm, bn, bk))
     tbs = sorted({1, 2, min(4, timesteps), timesteps})
     return [KernelConfig(bm, bn, bk, tb)

@@ -35,6 +35,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 __all__ = [
     "fused_lif_gemm",
@@ -45,6 +46,17 @@ __all__ = [
 ]
 
 DEFAULT_BLOCK = (128, 128, 128)  # (bm, bn, bk)
+
+
+def _tile_has_spike(s_tile):
+    """Scalar predicate: does the spike tile hold any nonzero entry?
+
+    An integer max reduction rather than ``jnp.any``: Mosaic cannot
+    relayout the boolean vector ``jnp.any`` reduces.
+    """
+    if not jnp.issubdtype(s_tile.dtype, jnp.floating):
+        s_tile = s_tile.astype(jnp.int32)
+    return jnp.max(jnp.abs(s_tile)) > 0
 
 
 def _fused_kernel_f32(
@@ -60,7 +72,7 @@ def _fused_kernel_f32(
 
     s_tile = s_ref[...]
     if skip_empty:
-        @pl.when(jnp.any(s_tile != 0))
+        @pl.when(_tile_has_spike(s_tile))
         def _accumulate():
             o_v_ref[...] += jnp.dot(
                 s_tile, w_ref[...], preferred_element_type=jnp.float32
@@ -106,15 +118,16 @@ def _fused_int_body(
     s_tile = s_ref[...]
 
     def _accumulate():
+        # int8 x int8 -> int32 is the MXU's integer path; Mosaic refuses
+        # int32 operands.
         o_v_ref[...] += jax.lax.dot_general(
-            s_tile.astype(jnp.int32),
-            w_ref[...].astype(jnp.int32),
+            s_tile, w_ref[...],
             (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.int32,
         )
 
     if skip_empty:
-        pl.when(jnp.any(s_tile != 0))(_accumulate)
+        pl.when(_tile_has_spike(s_tile))(_accumulate)
     else:
         _accumulate()
 
@@ -278,8 +291,8 @@ def _tile_bitmap_padded(s: jax.Array, bm: int, bk: int) -> jax.Array:
     """Per-tile spike bitmap of an already block-padded ``(T, M, K)`` stack.
 
     Entry ``[t, i, kk]`` is 1 iff the ``(bm, bk)`` spike tile at grid cell
-    ``(i, kk)`` of timestep ``t`` holds at least one spike.  int32 so the
-    kernel can read single entries through a ``(T, 1, 1)`` block.
+    ``(i, kk)`` of timestep ``t`` holds at least one spike.  int32, the
+    word type of the scalar memory the kernel reads it from.
     """
     t, m, k = s.shape
     tiles = s.reshape(t, m // bm, bm, k // bk, bk)
@@ -306,7 +319,7 @@ def spike_tile_bitmap(spikes: jax.Array, block: tuple = DEFAULT_BLOCK):
 
 
 def _tblk_int_body(
-    s_ref, w_ref, v_ref, bm_ref, o_v_ref, o_s_ref, get_threshold,
+    bm_ref, s_ref, w_ref, v_ref, o_v_ref, o_s_ref, get_threshold,
     *, n_k, n_t, leak_shift, soft_reset, v_min, v_max, skip_empty,
 ):
     """Vmem-stationary multi-timestep integer body.
@@ -315,26 +328,28 @@ def _tblk_int_body(
     timestep partials against it (``o_v_ref[t]`` doubles as the per-t
     accumulator); the sequential neuron program runs over t on the final
     k step, with the carried Vmem tile staying resident throughout.
-    Block-level sparsity comes from the host-computed bitmap: a zero
-    entry skips the whole (bm x bk) MXU dot for that (t, i, kk) tile.
+    Block-level sparsity comes from the host-computed bitmap, prefetched
+    into SMEM as a flat ``(T * gm * gk,)`` vector: a zero entry skips the
+    whole (bm x bk) MXU dot for that (t, i, kk) tile.
     """
-    k = pl.program_id(2)
+    i, k = pl.program_id(0), pl.program_id(2)
+    n_m = pl.num_programs(0)
 
     @pl.when(k == 0)
     def _init():
         o_v_ref[...] = jnp.zeros_like(o_v_ref)
         o_s_ref[...] = jnp.zeros_like(o_s_ref)
 
-    w_tile = w_ref[...].astype(jnp.int32)
+    w_tile = w_ref[...]
     for t in range(n_t):
         def _accumulate(t=t):
             o_v_ref[t] += jax.lax.dot_general(
-                s_ref[t].astype(jnp.int32), w_tile,
+                s_ref[t], w_tile,
                 (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.int32,
             )
         if skip_empty:
-            pl.when(bm_ref[t, 0, 0] != 0)(_accumulate)
+            pl.when(bm_ref[(t * n_m + i) * n_k + k] != 0)(_accumulate)
         else:
             _accumulate()
 
@@ -356,15 +371,15 @@ def _tblk_int_body(
             o_s_ref[t] = s
 
 
-def _tblk_kernel_scalar(s_ref, w_ref, v_ref, bm_ref, o_v_ref, o_s_ref,
+def _tblk_kernel_scalar(bm_ref, s_ref, w_ref, v_ref, o_v_ref, o_s_ref,
                         *, threshold, **kw):
-    _tblk_int_body(s_ref, w_ref, v_ref, bm_ref, o_v_ref, o_s_ref,
+    _tblk_int_body(bm_ref, s_ref, w_ref, v_ref, o_v_ref, o_s_ref,
                    lambda: threshold, **kw)
 
 
-def _tblk_kernel_vec(s_ref, w_ref, v_ref, bm_ref, t_ref, o_v_ref, o_s_ref,
+def _tblk_kernel_vec(bm_ref, s_ref, w_ref, v_ref, t_ref, o_v_ref, o_s_ref,
                      **kw):
-    _tblk_int_body(s_ref, w_ref, v_ref, bm_ref, o_v_ref, o_s_ref,
+    _tblk_int_body(bm_ref, s_ref, w_ref, v_ref, o_v_ref, o_s_ref,
                    lambda: t_ref[...], **kw)
 
 
@@ -381,36 +396,41 @@ def _tblk_call(kernel, s, w, v, block, interpret, thr=None, thr_pad=0):
     w = jnp.pad(w, ((0, pad_k), (0, pad_n)))
     v = jnp.pad(v, ((0, pad_m), (0, pad_n)))
     gm, gn, gk = s.shape[1] // bm, w.shape[1] // bn, s.shape[2] // bk
-    # Prologue: bitmap over the padded stack, so tilings stay aligned.
-    bitmap = _tile_bitmap_padded(s, bm, bk)
+    # Prologue: bitmap over the padded stack, so tilings stay aligned.  It
+    # rides in as a flat scalar-prefetch operand (SMEM): a per-tile VMEM
+    # block would break the (8, 128) block rule and put a scalar in VMEM.
+    bitmap = _tile_bitmap_padded(s, bm, bk).reshape(-1)
 
+    # Index maps receive the scalar-prefetch ref as a trailing argument.
     in_specs = [
-        pl.BlockSpec((t, bm, bk), lambda i, j, kk: (0, i, kk)),
-        pl.BlockSpec((bk, bn), lambda i, j, kk: (kk, j)),
-        pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
-        pl.BlockSpec((t, 1, 1), lambda i, j, kk: (0, i, kk)),
+        pl.BlockSpec((t, bm, bk), lambda i, j, kk, _: (0, i, kk)),
+        pl.BlockSpec((bk, bn), lambda i, j, kk, _: (kk, j)),
+        pl.BlockSpec((bm, bn), lambda i, j, kk, _: (i, j)),
     ]
-    operands = [s, w, v, bitmap]
+    operands = [s, w, v]
     if thr is not None:
         assert thr.shape == (n,), (thr.shape, n)
         operands.append(
             jnp.pad(thr, (0, pad_n), constant_values=thr_pad)[None, :])
-        in_specs.append(pl.BlockSpec((1, bn), lambda i, j, kk: (0, j)))
+        in_specs.append(pl.BlockSpec((1, bn), lambda i, j, kk, _: (0, j)))
 
     v_traj, s_out = pl.pallas_call(
         functools.partial(kernel, n_k=gk, n_t=t),
-        grid=(gm, gn, gk),
-        in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((t, bm, bn), lambda i, j, kk: (0, i, j)),
-            pl.BlockSpec((t, bm, bn), lambda i, j, kk: (0, i, j)),
-        ],
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(gm, gn, gk),
+            in_specs=in_specs,
+            out_specs=[
+                pl.BlockSpec((t, bm, bn), lambda i, j, kk, _: (0, i, j)),
+                pl.BlockSpec((t, bm, bn), lambda i, j, kk, _: (0, i, j)),
+            ],
+        ),
         out_shape=[
             jax.ShapeDtypeStruct((t, s.shape[1], w.shape[1]), jnp.int32),
             jax.ShapeDtypeStruct((t, s.shape[1], w.shape[1]), jnp.int32),
         ],
         interpret=interpret,
-    )(*operands)
+    )(bitmap, *operands)
     return v_traj[:, :m, :n], s_out[:, :m, :n]
 
 

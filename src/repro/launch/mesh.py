@@ -12,12 +12,9 @@ __all__ = ["make_production_mesh", "make_host_mesh", "make_mesh_compat"]
 
 
 def make_mesh_compat(shape, axes):
-    """jax.make_mesh across jax versions: ``axis_types`` (and the
-    ``jax.sharding.AxisType`` enum) only exist on newer releases."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(shape, axes)
-    return jax.make_mesh(shape, axes, axis_types=(axis_type.Auto,) * len(axes))
+    """``jax.make_mesh`` with every axis in automatic sharding mode."""
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

@@ -54,6 +54,7 @@ from repro import obs
 from repro.configs.base import get_config
 from repro.models import model as M
 from repro.models.transformer import init_decode_state
+from repro.runtime.compile_cache import configure_compile_cache
 from repro.serving import BatchWorker, StreamRequest, StreamWorker
 
 # Structured logging (repro.obs.logs): ``main()`` calls
@@ -440,6 +441,7 @@ def main():
     args = ap.parse_args()
 
     obs.logging_setup(json_mode=args.log_json)
+    configure_compile_cache()
 
     if args.snn:
         serve_snn(args)

@@ -28,6 +28,7 @@ from repro.configs.base import get_config
 from repro.data.pipeline import TokenPipeline
 from repro.launch.mesh import make_host_mesh
 from repro.models import model as M
+from repro.runtime.compile_cache import configure_compile_cache
 from repro.runtime.loop import LoopConfig, TrainingLoop
 
 logging.basicConfig(level=logging.INFO, format="%(asctime)s %(name)s %(message)s")
@@ -159,6 +160,7 @@ def main():
     args = ap.parse_args()
     if args.snn is None and args.arch is None:
         ap.error("pass --snn gesture|optical-flow or --arch <name>")
+    configure_compile_cache()
     if args.snn or args.arch.startswith("spidr-"):
         train_snn(args)
     else:

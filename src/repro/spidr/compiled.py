@@ -234,6 +234,13 @@ class StreamSession:
         """Per-slot open flags (index = slot id)."""
         return tuple(self._manager.active)
 
+    @property
+    def devices(self) -> frozenset:
+        """The devices holding the session's resident neuron state — after
+        a tick, the output of the jitted chunk step."""
+        return frozenset(d for leaf in jax.tree.leaves(self._manager.state)
+                         for d in leaf.devices())
+
     def state_dict(self) -> dict:
         """The session's full durable state as a deterministic pure-numpy
         tree (see ``StreamSessionManager.state_dict``): every slot's

@@ -1,0 +1,139 @@
+"""Compile rehearsal: the served path's fused kernels for a described TPU v5e.
+
+Nothing here runs on a chip.  The TPU compiler is installed with JAX and
+compiles for a chip that is described, not attached, so these tests catch
+what interpret mode cannot: block shapes the Mosaic lowering refuses,
+operand types the MXU does not take, and reductions it cannot relayout.
+Widths are the paper networks' real ones: gesture conv2 at batch 4 and an
+optical-flow 32->32 layer at batch 1.
+
+The topology is described inside a module fixture, never at import: only
+one process at a time may load the TPU library, and the test workers must
+all collect the same tests.
+
+The same file checks where the persistent compilation cache is placed.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from repro.kernels.autotune import _default_candidates
+from repro.kernels.fused_lif_gemm import (
+    fused_lif_gemm_int,
+    fused_lif_gemm_int_tblk,
+)
+from repro.runtime import compile_cache
+
+# (M, F, K): GEMM rows, fan-in, output channels.
+WIDTHS = {
+    "gesture_conv2_b4": (16384, 144, 16),
+    "flow_conv32_b1": (110592, 288, 32),
+}
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.sharding import SingleDeviceSharding
+
+    # A described-device compile is written to the persistent cache but
+    # cannot be read back without a chip: keep the cache off around them.
+    was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setenv("TPU_LOG_DIR", "disabled")
+        try:
+            topo = topologies.get_topology_desc(
+                platform="tpu", topology_name="v5e:2x2")
+        except Exception as e:  # no TPU compiler in this installation
+            pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+        yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was_on)
+    compilation_cache.reset_cache()
+
+
+def _sds(sharding, shape, dtype):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _assert_kernel_compiles(fn, *args):
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("width", sorted(WIDTHS))
+@pytest.mark.parametrize("per_channel", [False, True],
+                         ids=["scalar_thr", "channel_thr"])
+def test_fused_int_compiles(one_chip, width, per_channel):
+    m, f, k = WIDTHS[width]
+    args = [_sds(one_chip, (m, f), jnp.int8),
+            _sds(one_chip, (f, k), jnp.int8),
+            _sds(one_chip, (m, k), jnp.int32)]
+    if per_channel:
+        args.append(_sds(one_chip, (k,), jnp.int32))
+        fn = lambda s, w, v, t: fused_lif_gemm_int(s, w, v, threshold=t)
+    else:
+        fn = lambda s, w, v: fused_lif_gemm_int(s, w, v, threshold=3,
+                                                leak_shift=4)
+    _assert_kernel_compiles(fn, *args)
+
+
+@pytest.mark.parametrize("width", sorted(WIDTHS))
+@pytest.mark.parametrize("per_channel", [False, True],
+                         ids=["scalar_thr", "channel_thr"])
+def test_tblk_compiles(one_chip, width, per_channel):
+    m, f, k = WIDTHS[width]
+    args = [_sds(one_chip, (4, m, f), jnp.int8),
+            _sds(one_chip, (f, k), jnp.int8),
+            _sds(one_chip, (m, k), jnp.int32)]
+    if per_channel:
+        args.append(_sds(one_chip, (k,), jnp.int32))
+        fn = lambda s, w, v, t: fused_lif_gemm_int_tblk(s, w, v, threshold=t,
+                                                        soft_reset=True)
+    else:
+        fn = lambda s, w, v: fused_lif_gemm_int_tblk(s, w, v, threshold=3)
+    _assert_kernel_compiles(fn, *args)
+
+
+def test_every_autotune_candidate_compiles(one_chip):
+    """The tuner never offers a tiling the chip's compiler refuses."""
+    m, f, k = WIDTHS["gesture_conv2_b4"]
+    t = 4
+    cands = _default_candidates(m, f, k, timesteps=t)
+    assert cands
+    for cand in cands:
+        args = [_sds(one_chip, (cand.t_block, m, f), jnp.int8),
+                _sds(one_chip, (f, k), jnp.int8),
+                _sds(one_chip, (m, k), jnp.int32)]
+        _assert_kernel_compiles(
+            lambda s, w, v, cand=cand: fused_lif_gemm_int_tblk(
+                s, w, v, threshold=3, block=cand.block), *args)
+
+
+@pytest.fixture
+def restore_cache_dir():
+    old = jax.config.jax_compilation_cache_dir
+    yield
+    jax.config.update("jax_compilation_cache_dir", old)
+
+
+def test_cache_dir_from_environment_is_left_in_force(
+        monkeypatch, tmp_path, restore_cache_dir):
+    monkeypatch.setenv(compile_cache.CACHE_ENV, str(tmp_path))
+    before = jax.config.jax_compilation_cache_dir
+    assert compile_cache.configure_compile_cache() == str(tmp_path)
+    assert jax.config.jax_compilation_cache_dir == before
+
+
+def test_cache_dir_without_environment_is_fixed_in_checkout(
+        monkeypatch, restore_cache_dir):
+    monkeypatch.delenv(compile_cache.CACHE_ENV, raising=False)
+    first = compile_cache.configure_compile_cache()
+    assert jax.config.jax_compilation_cache_dir == first
+    assert compile_cache.configure_compile_cache() == first
+    checkout = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert first == os.path.join(checkout, ".jax_cache")

@@ -22,7 +22,8 @@ Usage:
   python tools/upgrade_drill.py --seed 7          # full geometry
 
 Exit status is non-zero if any configuration mismatches; the JSON report
-records per-config kill ticks and per-stream verdicts.
+records per-config kill ticks and per-stream verdicts.  The drill runs on
+the CPU in every process (it sets ``JAX_PLATFORMS=cpu`` before JAX loads).
 """
 from __future__ import annotations
 
@@ -165,11 +166,9 @@ def child_restore(cfg: dict, seed: int, snap_dir: str, out: str) -> None:
 # The drill.
 # ---------------------------------------------------------------------------
 def spawn(extra: list) -> subprocess.CompletedProcess:
-    env = dict(os.environ)
-    env.setdefault("JAX_PLATFORMS", "cpu")
+    # The children inherit the CPU pin ``main`` set before JAX loaded.
     return subprocess.run([sys.executable, os.path.abspath(__file__)] + extra,
-                          env=env, capture_output=True, text=True,
-                          timeout=1200)
+                          capture_output=True, text=True, timeout=1200)
 
 
 def drill_config(cfg: dict, seed: int) -> dict:
@@ -242,6 +241,9 @@ def main() -> int:
     ap.add_argument("--dir", default=None)
     ap.add_argument("--die-at", type=int, default=None, dest="die_at")
     args = ap.parse_args()
+    # A CPU drill: pin the parent too, before anything imports JAX, so it
+    # never holds an accelerator while its children run elsewhere.
+    os.environ["JAX_PLATFORMS"] = "cpu"
 
     if args.child is not None:
         cfg = json.loads(args.cfg)
