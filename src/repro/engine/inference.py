@@ -513,41 +513,52 @@ def _run_chunk_tiled(engine: SNNEngine, state: EngineState,
     act = events.astype(jnp.float32)
     new_vmem, counts_out, counts_in = [], [], []
     last = None  # (v_traj, s_stack) of the last weight layer
-    for el, v in zip(engine.layers, state.vmem):
+    for el, v, scope in zip(engine.layers, state.vmem, _layer_scopes(engine)):
         if el.kind == "conv":
-            counts_in.append(jnp.sum(act != 0, axis=(2, 3, 4)))
-            flat = act.reshape((t * b,) + act.shape[2:])
-            cols = im2col(flat, el.kh, el.kw, el.stride, el.padding)
-            p, f = cols.shape[1], cols.shape[2]
-            k = el.w_q.shape[1]
-            s_stack = cols.reshape(t, b * p, f).astype(jnp.int8)
-            v_traj, s = _layer_update_tblk(engine, el, s_stack,
-                                           v.reshape(b * p, k))
-            v_traj = v_traj.reshape((t,) + v.shape)
-            s = s.reshape((t,) + v.shape)
+            with jax.named_scope(f"{scope}.counts"):
+                counts_in.append(jnp.sum(act != 0, axis=(2, 3, 4)))
+            with jax.named_scope(f"{scope}.patches"):
+                flat = act.reshape((t * b,) + act.shape[2:])
+                cols = im2col(flat, el.kh, el.kw, el.stride, el.padding)
+                p, f = cols.shape[1], cols.shape[2]
+                k = el.w_q.shape[1]
+                s_stack = cols.reshape(t, b * p, f).astype(jnp.int8)
+            with jax.named_scope(f"{scope}.kernel"):
+                v_traj, s = _layer_update_tblk(engine, el, s_stack,
+                                               v.reshape(b * p, k))
+                v_traj = v_traj.reshape((t,) + v.shape)
+                s = s.reshape((t,) + v.shape)
             new_vmem.append(v_traj[-1])
-            counts_out.append(jnp.sum(s, axis=(2, 3, 4)))
+            with jax.named_scope(f"{scope}.counts"):
+                counts_out.append(jnp.sum(s, axis=(2, 3, 4)))
             act, last = s.astype(jnp.float32), (v_traj, s)
         elif el.kind == "fc":
-            flat = act.reshape(t, b, -1)
-            counts_in.append(jnp.sum(flat != 0, axis=2))
-            v_traj, s = _layer_update_tblk(engine, el,
-                                           flat.astype(jnp.int8), v)
+            with jax.named_scope(f"{scope}.patches"):
+                flat = act.reshape(t, b, -1)
+                s_stack = flat.astype(jnp.int8)
+            with jax.named_scope(f"{scope}.counts"):
+                counts_in.append(jnp.sum(flat != 0, axis=2))
+            with jax.named_scope(f"{scope}.kernel"):
+                v_traj, s = _layer_update_tblk(engine, el, s_stack, v)
             new_vmem.append(v_traj[-1])
-            counts_out.append(jnp.sum(s, axis=2))
+            with jax.named_scope(f"{scope}.counts"):
+                counts_out.append(jnp.sum(s, axis=2))
             act, last = s.astype(jnp.float32), (v_traj, s)
         elif el.kind == "pool":
-            act = _pool_stack(act, 2, 2)
+            with jax.named_scope(scope):
+                act = _pool_stack(act, 2, 2)
             new_vmem.append(None)
         elif el.kind == "adaptive_pool":
             kk = act.shape[2] // el.target_hw
-            act = _pool_stack(act, kk, kk)
+            with jax.named_scope(scope):
+                act = _pool_stack(act, kk, kk)
             new_vmem.append(None)
     v_traj, s_last = last
-    if spec.readout == "rate":
-        accs = state.readout_acc[None] + jnp.cumsum(s_last, axis=0)
-    else:
-        accs = v_traj
+    with jax.named_scope("spidr.readout"):
+        if spec.readout == "rate":
+            accs = state.readout_acc[None] + jnp.cumsum(s_last, axis=0)
+        else:
+            accs = v_traj
     slot_out = jnp.stack(counts_out, axis=1)   # (chunk_T, L, B)
     slot_in = jnp.stack(counts_in, axis=1)
     new_state = EngineState(
@@ -636,6 +647,22 @@ def _compile_engine(engine: SNNEngine, schedule: CoreSchedule,
                                device_parallel=bool(device_parallel))
 
 
+def _layer_scopes(engine: SNNEngine) -> list:
+    """The ``jax.named_scope`` of each engine layer, as device traces name
+    it: ``spidr.L{i}`` for the i-th weight layer (its ops under
+    ``.patches``, ``.kernel`` and ``.counts``) and ``spidr.pool{j}`` for
+    the j-th pool; the readout accumulates under ``spidr.readout``."""
+    names, weight, pool = [], 0, 0
+    for el in engine.layers:
+        if el.kind in ("conv", "fc"):
+            names.append(f"spidr.L{weight}")
+            weight += 1
+        else:
+            names.append(f"spidr.pool{pool}")
+            pool += 1
+    return names
+
+
 def _forward_t(engine: SNNEngine, state, x_t):
     """One timestep through every layer.
 
@@ -646,36 +673,45 @@ def _forward_t(engine: SNNEngine, state, x_t):
     """
     act = x_t  # float {0,1} spike plane (im2col needs float)
     new_state, counts_out, counts_in, out = [], [], [], None
-    for el, v in zip(engine.layers, state):
+    for el, v, scope in zip(engine.layers, state, _layer_scopes(engine)):
         if el.kind == "conv":
             b = act.shape[0]
-            counts_in.append(jnp.sum(act != 0, axis=(1, 2, 3)))
-            cols = im2col(act, el.kh, el.kw, el.stride, el.padding)  # (B,P,F)
-            rows, f = b * cols.shape[1], cols.shape[2]
-            k = el.w_q.shape[1]
-            v_next, s = _layer_update(
-                engine, el, cols.reshape(rows, f).astype(jnp.int8),
-                v.reshape(rows, k),
-            )
-            v_next = v_next.reshape(v.shape)
-            s = s.reshape(v.shape)
+            with jax.named_scope(f"{scope}.counts"):
+                counts_in.append(jnp.sum(act != 0, axis=(1, 2, 3)))
+            with jax.named_scope(f"{scope}.patches"):
+                cols = im2col(act, el.kh, el.kw, el.stride, el.padding)
+                rows, f = b * cols.shape[1], cols.shape[2]   # (B*P, F)
+                k = el.w_q.shape[1]
+                s2 = cols.reshape(rows, f).astype(jnp.int8)
+            with jax.named_scope(f"{scope}.kernel"):
+                v_next, s = _layer_update(engine, el, s2, v.reshape(rows, k))
+                v_next = v_next.reshape(v.shape)
+                s = s.reshape(v.shape)
             new_state.append(v_next)
-            counts_out.append(jnp.sum(s, axis=(1, 2, 3)))
+            with jax.named_scope(f"{scope}.counts"):
+                counts_out.append(jnp.sum(s, axis=(1, 2, 3)))
             act, out = s.astype(jnp.float32), (v_next, s)
         elif el.kind == "fc":
-            flat = act.reshape(act.shape[0], -1)
-            counts_in.append(jnp.sum(flat != 0, axis=1))
-            v_next, s = _layer_update(engine, el, flat.astype(jnp.int8), v)
+            with jax.named_scope(f"{scope}.patches"):
+                flat = act.reshape(act.shape[0], -1)
+                s2 = flat.astype(jnp.int8)
+            with jax.named_scope(f"{scope}.counts"):
+                counts_in.append(jnp.sum(flat != 0, axis=1))
+            with jax.named_scope(f"{scope}.kernel"):
+                v_next, s = _layer_update(engine, el, s2, v)
             new_state.append(v_next)
-            counts_out.append(jnp.sum(s, axis=1))
+            with jax.named_scope(f"{scope}.counts"):
+                counts_out.append(jnp.sum(s, axis=1))
             act, out = s.astype(jnp.float32), (v_next, s)
         elif el.kind == "pool":
-            act = maxpool2d(act)
+            with jax.named_scope(scope):
+                act = maxpool2d(act)
             new_state.append(None)
         elif el.kind == "adaptive_pool":
             hw = act.shape[1]
             kk = hw // el.target_hw
-            act = maxpool2d(act, window=kk, stride=kk)
+            with jax.named_scope(scope):
+                act = maxpool2d(act, window=kk, stride=kk)
             new_state.append(None)
     return new_state, out, jnp.stack(counts_out), jnp.stack(counts_in)
 
@@ -757,7 +793,8 @@ def run_chunk(
     def step(carry, x_t):
         vmem, acc, oc, ic = carry
         vmem, (v, s), c_out, c_in = _forward_t(engine, list(vmem), x_t)
-        acc = acc + s if spec.readout == "rate" else v
+        with jax.named_scope("spidr.readout"):
+            acc = acc + s if spec.readout == "rate" else v
         carry = (tuple(vmem), acc, oc + c_out, ic + c_in)
         ys = (
             (c_out, c_in) if collect_counts else None,

@@ -137,6 +137,13 @@ class StreamSessionManager:
             # to one host device so a fleet of sessions ticks on distinct
             # devices (the jitted step follows its committed operands).
             self.state = jax.device_put(self.state, device)
+        # Bytes of the resident state (what ``state_dict`` copies to the
+        # host) and of one tick's event array: span arguments, fixed for
+        # the session's lifetime.
+        self.state_nbytes = sum(leaf.nbytes
+                                for leaf in jax.tree.leaves(self.state))
+        self._frame_bytes = 4 * chunk_T * capacity * int(
+            np.prod(self._frame_shape))
         self.active = [False] * capacity
         self.ended = [False] * capacity   # delivered a short (final) chunk
         # Per-slot cumulative accounting (host side, O(capacity)).
@@ -192,7 +199,10 @@ class StreamSessionManager:
         assert self.active[slot], f"slot {slot} is not active"
         self.active[slot] = False
         self.ended[slot] = False
-        self.state = self._reset(self.state, jnp.int32(slot))
+        # Traced under the tick that delivered the stream's last chunk.
+        with self._tracer.span("session.close", cat="session",
+                               tick=self.ticks - 1, slot=slot):
+            self.state = self._reset(self.state, jnp.int32(slot))
 
     @property
     def occupancy(self) -> int:
@@ -233,18 +243,6 @@ class StreamSessionManager:
                     "Per-slot per-chunk nonzero event-tile fraction "
                     "(zero-skip opportunity)",
                     edges=obs_metrics.FRACTION_BUCKETS),
-                "slot_cycles": [reg.gauge(
-                    "spidr_slot_cycles",
-                    "Cumulative makespan cycles of the stream in each slot",
-                    labels={"slot": i}) for i in range(self.capacity)],
-                "slot_energy": [reg.gauge(
-                    "spidr_slot_energy_uj",
-                    "Cumulative energy of the stream in each slot (uJ)",
-                    labels={"slot": i}) for i in range(self.capacity)],
-                "slot_imbalance": [reg.gauge(
-                    "spidr_slot_load_imbalance",
-                    "Per-slot multi-core load imbalance (max/mean busy)",
-                    labels={"slot": i}) for i in range(self.capacity)],
             }
         return self._m
 
@@ -269,7 +267,13 @@ class StreamSessionManager:
 
     # -- the batched tick --------------------------------------------------
     def step(self, chunks: Dict[int, np.ndarray]) -> Dict[int, SlotUpdate]:
-        """Advance every slot by ``chunk_T`` timesteps in one fused call."""
+        """Advance every slot by ``chunk_T`` timesteps in one fused call.
+
+        Traced as ``run_chunk`` over its host phases: ``session.frame``
+        (the dense event array), ``session.upload``, ``session.dispatch``
+        (the jitted call, asynchronous), ``session.fetch`` (waiting for
+        the device and copying its outputs back) and ``session.price``.
+        """
         missing = [i for i in range(self.capacity)
                    if self.active[i] and i not in chunks]
         assert not missing, (
@@ -277,6 +281,43 @@ class StreamSessionManager:
             "open slot would advance its Vmem through zero-input timesteps "
             "and diverge from the whole-stream result — deliver every tick "
             "or close() the slot")
+        tracer, tick = self._tracer, self.ticks
+        with tracer.span("run_chunk", cat="session", tick=tick,
+                         slots=len(chunks)):
+            with tracer.span("session.frame", cat="session", tick=tick,
+                             bytes=self._frame_bytes):
+                ev, valid = self._frame(chunks)
+
+            # Telemetry pre-capture: cumulative counters only ever
+            # accumulate *deltas*, so totals are chunking-invariant (tested).
+            telemetry = bool(self._metrics)
+            if telemetry:
+                prev_cycles = self.slot_cycles.copy()
+                prev_energy = self.slot_energy_uj.copy()
+
+            with tracer.span("session.upload", cat="session", tick=tick):
+                # Straight to the session's device (None: the default one).
+                ev_dev = jax.device_put(ev, self.device)
+            with tracer.span("session.dispatch", cat="session", tick=tick):
+                self.state, out = self._step(self.state, ev_dev)
+            self.ticks += 1
+
+            fetched = (out.readouts,            # (chunk_T, capacity, ...)
+                       out.slot_spike_counts,   # (chunk_T, L, capacity)
+                       out.slot_input_counts)
+            with tracer.span("session.fetch", cat="session", tick=tick,
+                             bytes=sum(a.nbytes for a in fetched)):
+                readouts, slot_out, slot_in = (np.asarray(a) for a in fetched)
+            with tracer.span("session.price", cat="session", tick=tick):
+                updates = self._price(valid, readouts, slot_out, slot_in)
+            if telemetry:
+                self._record_tick(chunks, valid, slot_in, updates,
+                                  prev_cycles, prev_energy)
+        return updates
+
+    def _frame(self, chunks: Dict[int, np.ndarray]):
+        """The tick's dense ``(chunk_T, capacity, H, W, C)`` event array
+        and each delivering slot's valid timesteps."""
         ev = np.zeros((self.chunk_T, self.capacity) + self._frame_shape,
                       np.float32)
         valid = {}
@@ -293,32 +334,11 @@ class StreamSessionManager:
                 self.ended[slot] = True
             ev[:t, slot] = chunk
             valid[slot] = t
+        return ev, valid
 
-        # Telemetry pre-capture: cumulative counters only ever accumulate
-        # *deltas*, so totals are chunking-invariant (tested).
-        telemetry = bool(self._metrics)
-        if telemetry:
-            prev_cycles = self.slot_cycles.copy()
-            prev_energy = self.slot_energy_uj.copy()
-
-        # Upload straight to the session's device (None: the default one).
-        ev_dev = jax.device_put(ev, self.device)
-
-        if self._tracer:
-            with self._tracer.span("run_chunk", cat="session",
-                                   tick=self.ticks, slots=len(valid)):
-                self.state, out = self._step(self.state, ev_dev)
-                # Sync inside the span so it measures the device step, not
-                # just async dispatch (we host-transfer right below anyway).
-                out = jax.block_until_ready(out)
-        else:
-            self.state, out = self._step(self.state, ev_dev)
-        self.ticks += 1
-
-        readouts = np.asarray(out.readouts)          # (chunk_T, capacity, ...)
-        slot_out = np.asarray(out.slot_spike_counts)  # (chunk_T, L, capacity)
-        slot_in = np.asarray(out.slot_input_counts)
-
+    def _price(self, valid, readouts, slot_out, slot_in
+               ) -> Dict[int, SlotUpdate]:
+        """Price each delivering slot's chunk and build its reply."""
         updates = {}
         for slot, t in valid.items():
             # Price only this stream's own spikes: its per-slot input counts
@@ -370,9 +390,6 @@ class StreamSessionManager:
                 input_counts=(counts.copy()
                               if self._collect_chunk_counts else None),
             )
-        if telemetry:
-            self._record_tick(chunks, valid, slot_in, updates,
-                              prev_cycles, prev_energy)
         return updates
 
     def _record_tick(self, chunks, valid, slot_in, updates,
@@ -396,10 +413,6 @@ class StreamSessionManager:
             m["sparsity"].observe(float(np.clip(1.0 - density, 0.0, 1.0)))
             m["tile_frac"].observe(
                 self._nonzero_tile_frac(np.asarray(chunks[slot])))
-            m["slot_cycles"][slot].set(float(self.slot_cycles[slot]))
-            m["slot_energy"][slot].set(float(self.slot_energy_uj[slot]))
-            if self._schedule is not None:
-                m["slot_imbalance"][slot].set(float(self.slot_imbalance[slot]))
 
     # -- durability: serializable session state ----------------------------
     @property

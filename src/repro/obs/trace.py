@@ -16,7 +16,13 @@ Conventions:
   thread (thread names are emitted as ``thread_name`` metadata);
 * a disabled tracer hands back a shared no-op context manager, so the
   disabled cost of a span site is one truthiness check plus one attribute
-  call.
+  call;
+* an enabled span also enters ``jax.profiler.TraceAnnotation(name,
+  **args)``, so inside an active ``jax.profiler`` trace it lands on the
+  profiler's host line, on the same clock as the device's operations
+  (outside one the annotation costs next to nothing).  ``jax.profiler``
+  is imported at the first enabled span, so this module imports without
+  JAX, and spans without JAX record into the tracer alone.
 
 The tracer is intentionally unbounded: it is meant for bounded runs
 (compile, a serve session, an upgrade drill), not always-on production
@@ -56,6 +62,20 @@ class _NullSpan:
 
 
 _NULL_SPAN = _NullSpan()
+_annotation = None   # jax.profiler.TraceAnnotation, bound at first use
+
+
+def _profiler_annotation():
+    """``jax.profiler.TraceAnnotation``, or a null one without JAX."""
+    global _annotation
+    if _annotation is None:
+        try:
+            from jax.profiler import TraceAnnotation
+        except ImportError:
+            def TraceAnnotation(name, **args):
+                return _NULL_SPAN
+        _annotation = TraceAnnotation
+    return _annotation
 
 
 class Tracer:
@@ -95,7 +115,8 @@ class Tracer:
     def _span(self, name: str, cat: str, args: dict):
         t0 = self._now_us()
         try:
-            yield self
+            with _profiler_annotation()(name, **args):
+                yield self
         finally:
             t1 = self._now_us()
             ev = {
@@ -151,7 +172,9 @@ class Tracer:
         ]
         if extra_events:
             events = events + list(extra_events)
-        events.sort(key=lambda e: e.get("ts", 0.0))
+        # A parent opened in the same microsecond as its first child
+        # still leads it.
+        events.sort(key=lambda e: (e.get("ts", 0.0), -e.get("dur", 0.0)))
         return {
             "traceEvents": meta + events,
             "displayTimeUnit": "ms",

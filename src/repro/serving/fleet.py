@@ -277,15 +277,20 @@ class Fleet:
             if self._closed:
                 raise RuntimeError("fleet is shut down")
             t0 = time.monotonic()
-            self.scheduler.place()
-            progressed = False
-            for i, w in enumerate(self.workers):
-                if not self.scheduler.alive[i]:
-                    continue
-                if w.step():
-                    progressed = True
-                self._track_placements(i)
-                self._collect(i)
+            tracer = self._tracer
+            with tracer.span("fleet.step", cat="fleet", tick=self.ticks,
+                             queued=self.scheduler.queue_depth):
+                with tracer.span("fleet.place", cat="fleet",
+                                 tick=self.ticks):
+                    self.scheduler.place()
+                progressed = False
+                for i, w in enumerate(self.workers):
+                    if not self.scheduler.alive[i]:
+                        continue
+                    if w.step():
+                        progressed = True
+                    self._track_placements(i)
+                    self._collect(i)
             self.ticks += 1
             cfg = self.config
             if cfg.migrate_every and not cfg.batch \
@@ -380,21 +385,13 @@ class Fleet:
             if not self.scheduler.alive[to]:
                 raise ValueError(f"target replica {to} is dead")
             w_src, w_dst = self.workers[src], self.workers[to]
-
-            def _move():
+            with self._tracer.span("fleet.migrate", cat="fleet",
+                                   rid=req.rid, src=src, dst=to):
                 payload = w_src.sessions.export_slot(slot)
                 w_src.sessions.close(slot)
                 del w_src.slots[slot]
                 new_slot = w_dst.sessions.import_slot(payload)
                 w_dst.slots[new_slot] = req
-                return new_slot
-
-            if self._tracer:
-                with self._tracer.span("fleet.migrate", cat="fleet",
-                                       rid=req.rid, src=src, dst=to):
-                    new_slot = _move()
-            else:
-                new_slot = _move()
             h = self._handles.get(req.rid)
             if h is not None:
                 h.placements.append((to, new_slot))

@@ -286,9 +286,12 @@ class StreamWorker(_WorkerBase):
         buffers); the request part saves each request's mutable progress
         fields so the *same* objects callers hold are rolled back.
         """
+        with self._tracer.span("worker.mark", cat="serve", tick=self.ticks,
+                               bytes=self.sessions.state_nbytes):
+            session = self.sessions.state_dict()
         reqs = list(self.slots.values()) + self.waiting + self.done
         self._rewind_point = {
-            "session": self.sessions.state_dict(),
+            "session": session,
             "slots": dict(self.slots),
             "waiting": list(self.waiting),
             "done": list(self.done),
@@ -364,11 +367,7 @@ class StreamWorker(_WorkerBase):
         # part of the state a mid-tick failure must rewind to.
         self._mark()
         t0 = time.monotonic()
-        if self._tracer:
-            with self._tracer.span("serve.tick", cat="serve",
-                                   tick=self.ticks):
-                alive = self._step()
-        else:
+        with self._tracer.span("serve.tick", cat="serve", tick=self.ticks):
             alive = self._step()
         if self._metrics and alive:
             reg = self._metrics

@@ -241,6 +241,12 @@ class StreamSession:
         return frozenset(d for leaf in jax.tree.leaves(self._manager.state)
                          for d in leaf.devices())
 
+    @property
+    def state_nbytes(self) -> int:
+        """Bytes of the resident neuron state: what :meth:`state_dict`
+        copies to the host."""
+        return self._manager.state_nbytes
+
     def state_dict(self) -> dict:
         """The session's full durable state as a deterministic pure-numpy
         tree (see ``StreamSessionManager.state_dict``): every slot's

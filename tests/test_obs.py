@@ -267,8 +267,19 @@ class TestTracer:
         tr = obs.Tracer()
         _serve_stream(compiled1, _stream(t=6), 3, tracer=tr)
         spans = [e for e in tr.to_chrome()["traceEvents"] if e["ph"] == "X"]
-        assert [e["name"] for e in spans] == ["run_chunk", "run_chunk"]
+        tick = ["run_chunk", "session.frame", "session.upload",
+                "session.dispatch", "session.fetch", "session.price"]
+        assert [e["name"] for e in spans] == tick + tick + ["session.close"]
         assert all(e["cat"] == "session" for e in spans)
+        # Each tick's phases nest in its run_chunk and share its tick.
+        for parent, kids in ((spans[0], spans[1:6]), (spans[6], spans[7:12])):
+            end = parent["ts"] + parent["dur"]
+            assert all(parent["ts"] <= k["ts"] and k["ts"] + k["dur"] <= end
+                       for k in kids)
+            assert {k["args"]["tick"] for k in kids} == {
+                parent["args"]["tick"]}
+        assert spans[1]["args"]["bytes"] == 4 * 3 * 2 * 16 * 16 * 2
+        assert spans[4]["args"]["bytes"] > 0
 
     def test_compile_spans_on_default_tracer(self):
         obs.enable_tracing()
@@ -277,6 +288,135 @@ class TestTracer:
                  obs.default_tracer().to_chrome()["traceEvents"]
                  if e["ph"] == "X"}
         assert {"spidr.compile", "engine.build"} <= names
+
+
+def _profiled(tmp_path, fn):
+    """Run ``fn`` under the JAX profiler; the host line's spans, by name."""
+    import glob
+    import gzip
+
+    jax.profiler.start_trace(str(tmp_path), create_perfetto_trace=True)
+    try:
+        fn()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "**" / "perfetto_trace.json.gz"),
+                        recursive=True)
+    with gzip.open(path, "rt") as f:
+        doc = json.load(f)
+    events = doc["traceEvents"] if isinstance(doc, dict) else doc
+    spans = {}
+    for e in events:
+        if e.get("ph") == "X":
+            spans.setdefault(e["name"], []).append(e)
+    return spans
+
+
+def _inside(kid, parent) -> bool:
+    return (parent["ts"] <= kid["ts"]
+            and kid["ts"] + kid["dur"] <= parent["ts"] + parent["dur"])
+
+
+PROGRAM_SPANS = {"fleet.step", "fleet.place", "worker.mark", "serve.tick",
+                 "run_chunk", "session.frame", "session.upload",
+                 "session.dispatch", "session.fetch", "session.price",
+                 "session.close"}
+
+
+class TestProfilerSpans:
+    """An enabled tracer also writes its spans into an active
+    ``jax.profiler`` trace, on the device trace's clock."""
+
+    def _serve(self, compiled):
+        fleet = spidr.serve(compiled, capacity=2, chunk_T=3, mode="sync")
+        for rid in range(2):
+            fleet.submit(_stream(t=6, seed=rid), rid=rid)
+        fleet.drain()
+        fleet.shutdown()
+
+    def test_enabled_tracer_nests_program_spans_in_the_profile(
+            self, compiled1, tmp_path):
+        obs.enable_tracing()
+        spans = _profiled(tmp_path, lambda: self._serve(compiled1))
+        assert PROGRAM_SPANS <= set(spans)
+        steps = spans["fleet.step"]
+        # The worker marks its first rewind point when it is built.
+        spans["worker.mark"] = sorted(spans["worker.mark"],
+                                      key=lambda e: e["ts"])[1:]
+        for name, parent in (("fleet.place", "fleet.step"),
+                             ("worker.mark", "fleet.step"),
+                             ("serve.tick", "fleet.step"),
+                             ("run_chunk", "serve.tick"),
+                             ("session.close", "serve.tick"),
+                             ("session.fetch", "run_chunk"),
+                             ("session.price", "run_chunk")):
+            for kid in spans[name]:
+                assert any(_inside(kid, p) for p in spans[parent]), name
+        # Arguments ride along: every tick's phases carry its tick, the
+        # byte counts are those of the work inside.
+        fetch = spans["session.fetch"]
+        chunks = spans["run_chunk"]
+        assert [f["args"]["tick"] for f in fetch] == [
+            c["args"]["tick"] for c in chunks] == ["0", "1"]
+        assert all(int(f["args"]["bytes"]) > 0 for f in fetch)
+        assert all("queued" in s["args"] for s in steps)
+        # A slot retired in a tick is traced under that tick.
+        assert {c["args"]["tick"] for c in spans["session.close"]} == {"1"}
+        marks = spans["worker.mark"]
+        assert all(int(m["args"]["bytes"]) > 0 for m in marks)
+        assert {"spidr.compile", "engine.build"}.isdisjoint(spans)
+
+    def test_disabled_tracer_writes_nothing_into_the_profile(
+            self, compiled1, tmp_path):
+        spans = _profiled(tmp_path, lambda: self._serve(compiled1))
+        assert PROGRAM_SPANS.isdisjoint(spans)
+
+    def test_disabled_span_is_the_shared_null_span(self):
+        from repro.obs.trace import _NULL_SPAN
+
+        assert obs.Tracer(enabled=False).span("s", k=1) is _NULL_SPAN
+
+    def test_obs_imports_and_traces_without_jax(self):
+        code = (
+            "import sys, types\n"
+            "sys.modules['jax'] = None\n"
+            "pkg = types.ModuleType('repro'); pkg.__path__ = ['src/repro']\n"
+            "sys.modules['repro'] = pkg\n"
+            "import repro.obs as obs\n"
+            "t = obs.Tracer()\n"
+            "with t.span('s', k=1):\n"
+            "    pass\n"
+            "assert [e['name'] for e in t.events] == ['s']\n")
+        proc = subprocess.run([sys.executable, "-c", code],
+                              capture_output=True, text=True)
+        assert proc.returncode == 0, proc.stderr
+
+    def test_tracing_leaves_every_answer_bit_identical(self, compiled1):
+        stream = _stream(t=6, seed=3)
+        off = _serve_stream(compiled1, stream, 2,
+                            tracer=obs.Tracer(enabled=False))
+        on = _serve_stream(compiled1, stream, 2, tracer=obs.Tracer())
+        np.testing.assert_array_equal(on.readout, off.readout)
+        assert (on.cycles, on.energy_uj, on.spikes) == (
+            off.cycles, off.energy_uj, off.spikes)
+
+    @pytest.mark.parametrize("backend", ["jnp", "fused"])
+    def test_chunk_step_names_every_weight_layer(self, backend):
+        from repro.engine.inference import init_state, run_chunk
+
+        spec = spidr_gesture.reduced(hw=(16, 16), timesteps=2)
+        compiled = spidr.compile(
+            spec, init_params(jax.random.PRNGKey(0), spec),
+            spidr.DeployTarget(backend=backend))
+        engine = compiled.engine
+        step = jax.jit(lambda st, ev: run_chunk(
+            engine, st, ev, collect_counts=True, collect_readouts=True))
+        text = step.lower(init_state(engine, 2),
+                          jnp.zeros((2, 2, 16, 16, 2))).as_text(
+                              debug_info=True)
+        for scope in ("spidr.L0.kernel", "spidr.L0.patches",
+                      "spidr.L0.counts", "spidr.pool0", "spidr.readout"):
+            assert scope in text, scope
 
 
 # ---------------------------------------------------------------------------
