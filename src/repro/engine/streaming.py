@@ -55,9 +55,11 @@ from ..core.pipeline import PipelineState
 from ..obs import metrics as obs_metrics
 from ..obs import trace as obs_trace
 from .cost import estimate_cost, estimate_multicore_cost
-from .inference import SNNEngine, init_state, reset_slot, run_chunk
+from .inference import (EngineState, SNNEngine, init_state, reset_slot,
+                        run_chunk)
 
-__all__ = ["SESSION_SCHEMA_VERSION", "SlotUpdate", "StreamSessionManager"]
+__all__ = ["SESSION_SCHEMA_VERSION", "SessionMark", "SlotUpdate",
+           "StreamSessionManager"]
 
 # Serialized-session schema version (see ``StreamSessionManager.state_dict``).
 # Bump when the snapshot layout changes; ``load_state_dict`` refuses newer
@@ -86,6 +88,30 @@ class SlotUpdate:
     # ``launch/serve.py --trace-out`` to re-price finished streams with
     # ``collect_timeline=True`` for the per-stream pipeline timeline).
     input_counts: Optional[np.ndarray] = None
+
+
+@dataclasses.dataclass(frozen=True)
+class SessionMark:
+    """An in-process rewind point of a session (:meth:`StreamSessionManager.
+    mark`): the resident :class:`EngineState` held by reference, and a host
+    copy of the session table.
+
+    The device state is never copied: JAX arrays are immutable and neither
+    the chunk step nor ``reset_slot`` donates its input, so the state a
+    mark holds stays valid and unchanged however the session advances.
+    The handshake clocks are held by reference too: a slot's
+    ``PipelineState`` (or per-core list of them) is only ever replaced,
+    never mutated.  Unlike :meth:`StreamSessionManager.
+    state_dict` a mark is not durable: it lives as long as the process and
+    its device.
+    """
+
+    state: EngineState       # the session's resident state, by reference
+    active: tuple
+    ended: tuple
+    counters: tuple          # copies of the per-slot cumulative arrays
+    pipe_state: tuple
+    ticks: int
 
 
 class StreamSessionManager:
@@ -138,8 +164,9 @@ class StreamSessionManager:
             # devices (the jitted step follows its committed operands).
             self.state = jax.device_put(self.state, device)
         # Bytes of the resident state (what ``state_dict`` copies to the
-        # host) and of one tick's event array: span arguments, fixed for
-        # the session's lifetime.
+        # host and a ``mark`` pins on the device), of the per-slot arrays a
+        # ``mark`` copies, and of one tick's event array: span arguments,
+        # fixed for the session's lifetime.
         self.state_nbytes = sum(leaf.nbytes
                                 for leaf in jax.tree.leaves(self.state))
         self._frame_bytes = 4 * chunk_T * capacity * int(
@@ -162,9 +189,14 @@ class StreamSessionManager:
         self._slot_route_cycles = np.zeros((capacity, n_cores), np.int64)
         self.slot_core_cycles = np.zeros((capacity, n_cores), np.int64)
         self.slot_imbalance = np.ones(capacity, np.float64)
+        self.table_nbytes = 2 * capacity + sum(
+            a.nbytes for a in self._counters())
         self.ticks = 0
         # One jitted step for the session's lifetime: fixed (chunk_T,
-        # capacity, H, W, C) event shape, fixed state shapes.
+        # capacity, H, W, C) event shape, fixed state shapes.  A ``mark``
+        # aliases the state passed to this step and to ``_reset``: neither
+        # may donate it (``tests/test_streaming_durability.py`` fails if
+        # one does).
         self._step = jax.jit(
             lambda st, ev: run_chunk(engine, st, ev, collect_counts=True,
                                      collect_readouts=True)
@@ -414,6 +446,43 @@ class StreamSessionManager:
             m["tile_frac"].observe(
                 self._nonzero_tile_frac(np.asarray(chunks[slot])))
 
+    # -- in-process rewind point -------------------------------------------
+    def _counters(self) -> tuple:
+        """The per-slot cumulative arrays, which a tick updates in place."""
+        return (self.slot_timesteps, self.slot_spikes, self.slot_cycles,
+                self.slot_energy_uj, self._slot_route_cycles,
+                self.slot_core_cycles, self.slot_imbalance)
+
+    def mark(self) -> SessionMark:
+        """A rewind point at the current tick, for :meth:`rewind`.
+
+        Holds the resident device state by reference and copies only the
+        host-side session table (``table_nbytes``): no device-to-host
+        transfer.  For a durable snapshot use :meth:`state_dict`.
+        """
+        return SessionMark(
+            state=self.state,
+            active=tuple(self.active),
+            ended=tuple(self.ended),
+            counters=tuple(a.copy() for a in self._counters()),
+            pipe_state=tuple(self._pipe_state),
+            ticks=self.ticks,
+        )
+
+    def rewind(self, mark: SessionMark) -> None:
+        """Return the session to ``mark``, bit-exactly, with no transfer.
+
+        The mark stays valid: the same mark may be rewound to again.
+        """
+        self.state = mark.state
+        self.active = list(mark.active)
+        self.ended = list(mark.ended)
+        (self.slot_timesteps, self.slot_spikes, self.slot_cycles,
+         self.slot_energy_uj, self._slot_route_cycles, self.slot_core_cycles,
+         self.slot_imbalance) = (a.copy() for a in mark.counters)
+        self._pipe_state = list(mark.pipe_state)
+        self.ticks = mark.ticks
+
     # -- durability: serializable session state ----------------------------
     @property
     def n_cores(self) -> int:
@@ -446,7 +515,9 @@ class StreamSessionManager:
 
         Every array is a fresh host copy — nothing aliases the manager's
         live buffers, so ``state_dict`` at tick k is immutable evidence of
-        tick k no matter how the session advances afterwards.  The schema
+        tick k no matter how the session advances afterwards, and outlives
+        the process and the device.  (The in-process rewind point is
+        :meth:`mark`, which copies no device state.)  The schema
         is pinned by ``tests/test_streaming_durability.py``; round-tripping
         through :meth:`load_state_dict` is bit-exact (tested for any
         snapshot boundary, chunking and slot open/close interleaving).

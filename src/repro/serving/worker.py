@@ -282,19 +282,25 @@ class StreamWorker(_WorkerBase):
     def _mark(self):
         """Record the last-completed-tick state the next rewind returns to.
 
-        The session part is a pure-numpy ``state_dict`` (never aliases live
-        buffers); the request part saves each request's mutable progress
-        fields so the *same* objects callers hold are rolled back.
+        The session part is ``sessions.mark()``: the resident device state
+        held by reference (immutable arrays, never donated) and a host copy
+        of the session table — no device-to-host transfer.  The request
+        part saves the mutable progress of every request a tick can touch,
+        those in a slot or waiting, so the *same* objects callers hold are
+        rolled back.  A tick only appends to ``done`` and never touches a
+        finished request again, so ``done`` rewinds by truncation.
         """
+        sessions = self.sessions
         with self._tracer.span("worker.mark", cat="serve", tick=self.ticks,
-                               bytes=self.sessions.state_nbytes):
-            session = self.sessions.state_dict()
-        reqs = list(self.slots.values()) + self.waiting + self.done
+                               bytes=sessions.table_nbytes,
+                               pinned=sessions.state_nbytes):
+            session = sessions.mark()
+        reqs = list(self.slots.values()) + self.waiting
         self._rewind_point = {
             "session": session,
             "slots": dict(self.slots),
             "waiting": list(self.waiting),
-            "done": list(self.done),
+            "done": len(self.done),
             "ticks": self.ticks,
             "reqs": [(r, r.cursor, r.readout, r.cycles, r.energy_uj,
                       r.first_reply_at, r.done_at, r.input_counts)
@@ -303,10 +309,10 @@ class StreamWorker(_WorkerBase):
 
     def _rewind(self, *args, **kwargs):
         cp = self._rewind_point
-        self.sessions.load_state_dict(cp["session"])
+        self.sessions.rewind(cp["session"])
         self.slots = dict(cp["slots"])
         self.waiting = list(cp["waiting"])
-        self.done = list(cp["done"])
+        del self.done[cp["done"]:]
         self.ticks = cp["ticks"]
         for r, cur, ro, cyc, uj, fr, da, ic in cp["reqs"]:
             r.cursor, r.readout, r.cycles, r.energy_uj = cur, ro, cyc, uj

@@ -244,8 +244,23 @@ class StreamSession:
     @property
     def state_nbytes(self) -> int:
         """Bytes of the resident neuron state: what :meth:`state_dict`
-        copies to the host."""
+        copies to the host and a :meth:`mark` pins on the device."""
         return self._manager.state_nbytes
+
+    @property
+    def table_nbytes(self) -> int:
+        """Bytes of the session table a :meth:`mark` copies on the host."""
+        return self._manager.table_nbytes
+
+    def mark(self):
+        """An in-process rewind point (see ``StreamSessionManager.mark``):
+        the resident device state by reference, the session table copied.
+        No device-to-host transfer; not durable."""
+        return self._manager.mark()
+
+    def rewind(self, mark) -> None:
+        """Return the session to a :meth:`mark`, bit-exactly."""
+        self._manager.rewind(mark)
 
     def state_dict(self) -> dict:
         """The session's full durable state as a deterministic pure-numpy

@@ -362,8 +362,13 @@ class TestProfilerSpans:
         assert all("queued" in s["args"] for s in steps)
         # A slot retired in a tick is traced under that tick.
         assert {c["args"]["tick"] for c in spans["session.close"]} == {"1"}
+        # The rewind point copies the session table and pins the device
+        # state by reference.
         marks = spans["worker.mark"]
-        assert all(int(m["args"]["bytes"]) > 0 for m in marks)
+        pinned = compiled1.open_stream(capacity=2, chunk_T=3).state_nbytes
+        assert all(0 < int(m["args"]["bytes"]) < int(m["args"]["pinned"])
+                   for m in marks)
+        assert {int(m["args"]["pinned"]) for m in marks} == {pinned}
         assert {"spidr.compile", "engine.build"}.isdisjoint(spans)
 
     def test_disabled_tracer_writes_nothing_into_the_profile(

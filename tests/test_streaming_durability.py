@@ -488,3 +488,129 @@ class TestDurableServer:
         with pytest.raises(RestartableFailure, match="wedged"):
             srv.step()
         assert srv.restarts == 3  # 1 try + max_restarts replays
+
+
+# ---------------------------------------------------------------------------
+# The in-process rewind point: device state by reference, never copied.
+# ---------------------------------------------------------------------------
+def _state_leaves(st):
+    """An ``EngineState``'s arrays, or a ``state_dict``'s engine part."""
+    if isinstance(st, dict):
+        return ([v for v in st["vmem"] if v is not None]
+                + [st["readout_acc"], st["out_counts"], st["in_counts"]])
+    return ([v for v in st.vmem if v is not None]
+            + [st.readout_acc, st.out_counts, st.in_counts])
+
+
+def _assert_same_snapshot(a, b):
+    assert jax.tree.structure(a) == jax.tree.structure(b)
+    for x, y in zip(jax.tree.leaves(a), jax.tree.leaves(b)):
+        np.testing.assert_array_equal(x, y)
+
+
+class TestRewindPoint:
+    @pytest.mark.parametrize("op", ["step", "close"])
+    def test_mark_outlives_the_device_op_that_consumes_it(self, op):
+        # The chunk step and reset_slot both take the marked state as
+        # input: if either donated it, its arrays would be deleted here.
+        sess = _compiled().open_stream(2, 2)
+        s0 = sess.open()
+        rng = np.random.default_rng(5)
+        sess.step({s0: _chunk(rng, 2)})
+        before = sess.state_dict()
+        mark = sess.mark()
+        if op == "step":
+            sess.step({s0: _chunk(rng, 2)})
+        else:
+            sess.close(s0)
+        leaves = _state_leaves(mark.state)
+        assert not any(leaf.is_deleted() for leaf in leaves)
+        for got, want in zip(leaves, _state_leaves(before["engine_state"])):
+            np.testing.assert_array_equal(got, want)
+        # Rewinding twice to the same mark lands on the same state.
+        for _ in range(2):
+            sess.rewind(mark)
+            _assert_same_snapshot(sess.state_dict(), before)
+            sess.step({s0: _chunk(rng, 2)})
+
+    def test_worker_rewind_point_pins_the_pre_tick_state(self):
+        srv = StreamWorker(_compiled(capacity=2), 2, 2)
+        for rid, req in sorted(TestDurableServer()._requests().items()):
+            srv.submit(req)
+        ticks = 0
+        while True:
+            pre = _state_leaves(srv.sessions._manager.state)
+            want = _state_leaves(srv.sessions.state_dict()["engine_state"])
+            if not srv.step():
+                break
+            ticks += 1
+            held = _state_leaves(srv._rewind_point["session"].state)
+            assert all(h is p for h, p in zip(held, pre))
+            assert not any(h.is_deleted() for h in held)
+            for h, w in zip(held, want):
+                np.testing.assert_array_equal(h, w)
+        assert ticks == srv.ticks > 1
+
+    def test_mark_holds_no_finished_request(self):
+        srv = StreamWorker(_compiled(capacity=2), 2, 2)
+        for rid, req in sorted(TestDurableServer()._requests().items()):
+            srv.submit(req)
+        finished_before_a_tick = 0
+        while True:
+            live = {id(r) for r in list(srv.slots.values()) + srv.waiting}
+            finished = list(srv.done)
+            alive = srv.step()
+            cp = srv._rewind_point
+            assert {id(r[0]) for r in cp["reqs"]} == live
+            assert cp["done"] == len(finished)
+            finished_before_a_tick += bool(finished)
+            if not alive:
+                break
+        assert finished_before_a_tick and len(srv.done) == 4
+
+    @pytest.mark.parametrize("n_cores", [1, 4])
+    @pytest.mark.parametrize("fault", ["fail_at_tick", "after_close"])
+    def test_poisoned_tick_with_retire_and_admit_replays_bit_exactly(
+            self, fault, n_cores):
+        # Capacity 2, chunk 2, clips of 4, 6, 4, 2 steps: tick 3 admits
+        # clip 2 into the slot clip 0 freed, and finishes clip 1 and
+        # resets its slot.  ``fail_at_tick`` poisons it after the chunk
+        # step; ``after_close`` after clip 1 is already on ``done`` and its
+        # slot reset.
+        compiled = _compiled(n_cores=n_cores, capacity=2)
+        rng = np.random.default_rng(41)
+        clips = [(rng.random((t,) + HW + (2,)) < 0.1).astype(np.float32)
+                 for t in (4, 6, 4, 2)]
+
+        def serve(poison):
+            fleet = spidr.serve(compiled, capacity=2, chunk_T=2, mode="sync")
+            w = fleet.workers[0]
+            if poison == "fail_at_tick":
+                w.fail_at_tick = 3
+            elif poison == "after_close":
+                real_close, fired = w.sessions.close, []
+
+                def close(slot=None):
+                    real_close(slot)
+                    if w.ticks + 1 == 3 and not fired:
+                        fired.append(slot)
+                        raise RestartableFailure("poisoned after a reset")
+
+                w.sessions.close = close
+            handles = [fleet.submit(ev, rid=rid)
+                       for rid, ev in enumerate(clips)]
+            fleet.drain()
+            seen = fleet._done_seen[0]
+            got = ([r.rid for r in w.done], seen, w.restarts,
+                   [(h.status, np.asarray(h.readout).tolist(), h.cycles,
+                     h.energy_uj) for h in handles])
+            fleet.shutdown()
+            return got
+
+        ref_order, ref_seen, ref_restarts, ref = serve(None)
+        order, seen, restarts, got = serve(fault)
+        assert ref_restarts == 0 and restarts == 1
+        assert order == ref_order == [0, 1, 2, 3]
+        assert seen == ref_seen == 4
+        assert got == ref
+        assert all(status == "done" for status, *_ in got)
