@@ -1,13 +1,16 @@
 """Plain reference of the served network and of its chip-cost accounting.
 
 Written from the configuration file alone (``bench/configs/<name>.json``)
-and the paper's datapath: a 3x3 spiking convolution is an integer
-convolution of the binary input plane with the layer's signed weights,
-saturated to the Vmem width; the neuron adds it to its (leaked) Vmem,
-saturates, fires at the integer threshold and resets (to 0, or by the
-threshold).  Pools take the max of binary planes.  The readout is the
-summed last-layer spikes (``"rate"``) or the last layer's Vmem
-(``"vmem"``).  It imports nothing of the program under test.
+and the paper's datapath; how the layers connect is the network
+description's (``bench/networks/<topology>.py``, found by
+``bench/named.py``; ``chain.py`` for the paper's networks).  A 3x3
+spiking convolution is an integer convolution of the binary input plane
+with the layer's signed weights, saturated to the Vmem width; the neuron
+adds it to its (leaked) Vmem, saturates, fires at the integer threshold
+and resets (to 0, or by the threshold).  Pools take the max of binary
+planes.  The readout is the summed last-layer spikes (``"rate"``) or the
+last layer's Vmem (``"vmem"``).  It imports nothing of the program under
+test.
 
 Sums of 0/1 spikes times weights of at most 8 in magnitude stay far below
 2**24, so a float32 convolution at ``HIGHEST`` precision is exact; the
@@ -23,140 +26,42 @@ from __future__ import annotations
 
 import math
 
-import jax
-import jax.numpy as jnp
 import numpy as np
 
-from . import work
+from . import named, work
 
 __all__ = ["chip_cost", "reference_run", "weight_layers"]
 
-_HIGHEST = jax.lax.Precision.HIGHEST
-
 
 def weight_layers(cfg: dict) -> list:
-    """The configuration's conv and fc layers, in order."""
-    return [layer for layer in cfg["layers"] if layer["kind"] in ("conv", "fc")]
+    """The configuration's weight layers, in order: each with its
+    ``fan_in``, ``c_out``, ``thr_int``, ``shape`` and ``spatial``, as its
+    network description gives them."""
+    return named.network(cfg).weight_layers(cfg)
 
 
 def _layer_geometry(cfg: dict) -> list:
-    """(kind, fan_in, out_channels, out_positions) per weight layer."""
-    return [(layer["kind"], f, k, p) for layer, (p, f, k)
+    """(spatial, fan_in, out_channels, out_positions) per weight layer."""
+    return [(layer["spatial"], f, k, p) for layer, (p, f, k)
             in zip(weight_layers(cfg), work.layer_work(cfg))]
-
-
-def _state_shapes(cfg: dict, batch: int) -> list:
-    h, w = cfg["input_hw"]
-    shapes = []
-    for layer in cfg["layers"]:
-        kind = layer["kind"]
-        if kind == "conv":
-            p, s = layer["padding"], layer["stride"]
-            h = (h + 2 * p - layer["kh"]) // s + 1
-            w = (w + 2 * p - layer["kw"]) // s + 1
-            shapes.append((batch, h, w, layer["c_out"]))
-        elif kind == "fc":
-            shapes.append((batch, layer["c_out"]))
-        elif kind == "pool":
-            h, w = h // 2, w // 2
-        elif kind == "adaptive_pool":
-            h = w = layer["target_hw"]
-    return shapes
-
-
-def _max_pool(x, k: int):
-    return jax.lax.reduce_window(x, -jnp.inf, jax.lax.max,
-                                 (1, k, k, 1), (1, k, k, 1), "VALID")
-
-
-def _timestep_fn(cfg: dict, vmem_bits: int):
-    """One timestep through every layer for a batch of clips."""
-    v_min, v_max = -(1 << (vmem_bits - 1)), (1 << (vmem_bits - 1)) - 1
-    neuron = cfg["neuron"]
-    leak = neuron["leak_shift"] if neuron["model"] == "lif" else 0
-    hard = neuron["reset"] == "hard"
-
-    def fire(acc, v, thr):
-        partial = jnp.clip(acc.astype(jnp.int32), v_min, v_max)
-        if leak > 0:
-            v = v - (v >> leak)
-        v = jnp.clip(v + partial, v_min, v_max)
-        s = (v >= thr).astype(jnp.int32)
-        v = v * (1 - s) if hard else jnp.clip(v - s * thr, v_min, v_max)
-        return v, s
-
-    def step(weights, vmem, x):
-        act, wi, new_vmem, counts = x, 0, [], []
-        for layer in cfg["layers"]:
-            kind = layer["kind"]
-            if kind in ("conv", "fc"):
-                w, v = weights[wi], vmem[wi]
-                if kind == "conv":
-                    counts.append(jnp.sum(act != 0, axis=(1, 2, 3)))
-                    p, st = layer["padding"], layer["stride"]
-                    acc = jax.lax.conv_general_dilated(
-                        act, w, (st, st), ((p, p), (p, p)),
-                        dimension_numbers=("NHWC", "HWIO", "NHWC"),
-                        precision=_HIGHEST)
-                else:
-                    act = act.reshape(act.shape[0], -1)
-                    counts.append(jnp.sum(act != 0, axis=1))
-                    acc = jnp.dot(act, w, precision=_HIGHEST)
-                v, s = fire(acc, v, layer["thr_int"])
-                new_vmem.append(v)
-                act = s.astype(jnp.float32)
-                wi += 1
-            elif kind == "pool":
-                act = _max_pool(act, 2)
-            elif kind == "adaptive_pool":
-                act = _max_pool(act, act.shape[1] // layer["target_hw"])
-        return new_vmem, s, v, jnp.stack(counts, axis=1)
-
-    return jax.jit(step)
 
 
 def reference_run(cfg: dict, weights: list, clips: np.ndarray,
                   vmem_bits: int | None = None, budget_bytes: float = 1.5e9):
-    """Serve ``clips`` (N, T, H, W, C) whole through the plain reference.
+    """Serve ``clips`` (N, T, H, W, C) whole through the plain reference
+    of the configuration's network description.
 
     ``weights`` are the configuration's integer weights, one (F, K) array
-    per weight layer with the fan-in in (kh, kw, c_in) order.  Returns
-    ``(readouts, input_counts)``: readouts (N, classes) or (N, H, W, K)
-    int32, and the per-timestep per-layer input-spike counts (N, T, L).
-    ``vmem_bits`` defaults to the configuration's; a smaller value gives
-    the lower-precision control.  Clips run in blocks whose state and
-    activations (about three int32 copies of every layer's Vmem per clip)
-    fit in ``budget_bytes``.
+    per weight layer with the fan-in in the order of the layer's
+    ``shape`` (kh, kw, c_in for a conv).  Returns ``(readouts,
+    input_counts)``: readouts (N, classes) or (N, H, W, K) int32, and the
+    per-timestep per-layer input-spike counts (N, T, L).  ``vmem_bits``
+    defaults to the configuration's; a smaller value gives the
+    lower-precision control.  Clips run in blocks whose state and
+    activations fit in ``budget_bytes``.
     """
-    vmem_bits = cfg["vmem_bits"] if vmem_bits is None else vmem_bits
-    step = _timestep_fn(cfg, vmem_bits)
-    ws = []
-    for layer, w in zip(weight_layers(cfg), weights):
-        w = jnp.asarray(np.asarray(w), jnp.float32)
-        if layer["kind"] == "conv":
-            w = w.reshape(layer["kh"], layer["kw"], layer["c_in"],
-                          layer["c_out"])
-        ws.append(w)
-    n, t_len = clips.shape[:2]
-    per_clip = 3 * 4 * sum(int(np.prod(s)) for s in _state_shapes(cfg, 1))
-    block = int(max(1, min(n, budget_bytes // per_clip)))
-    readouts, counts = [], []
-    for lo in range(0, n, block):
-        x = clips[lo:lo + block]
-        b = x.shape[0]
-        vmem = [jnp.zeros(s, jnp.int32) for s in _state_shapes(cfg, b)]
-        acc = None
-        per_t = []
-        for t in range(t_len):
-            vmem, s, v, c = step(ws, vmem, jnp.asarray(x[:, t], jnp.float32))
-            if cfg["readout"] == "rate":
-                acc = s if acc is None else acc + s
-            else:
-                acc = v
-            per_t.append(np.asarray(c))
-        readouts.append(np.asarray(acc, np.int32))
-        counts.append(np.stack(per_t, axis=1))
-    return np.concatenate(readouts), np.concatenate(counts).astype(np.int64)
+    return named.network(cfg).reference_run(cfg, weights, clips, vmem_bits,
+                                            budget_bytes)
 
 
 # ---------------------------------------------------------------------------
@@ -180,9 +85,12 @@ _SHARES = {"cim_macros": 0.62, "s2a": 0.08, "input_loader": 0.10,
            "control_clock": 0.14, "data_movement": 0.06}
 
 
-def _mapping(kind: str, fan_in: int, k: int, positions: int,
+def _mapping(spatial: bool, fan_in: int, k: int, positions: int,
              weight_bits: int):
-    """(active macros, channel tiles, weight-stationary passes)."""
+    """(active macros, channel tiles, weight-stationary passes).  A
+    spatial layer's weights slide over its output positions, which the
+    macros take 16 (the IFspad columns) to a pass; any other layer's
+    positions take a pass each."""
     if fan_in <= _CM_ROWS * 3:
         pipelines, macros, cap = _N_NU, 3, _CM_ROWS * 3
     else:
@@ -190,8 +98,7 @@ def _mapping(kind: str, fan_in: int, k: int, positions: int,
     fan_in_tiles = math.ceil(fan_in / cap)
     parallel = pipelines * (48 // weight_bits)
     channel_tiles = math.ceil(k / parallel)
-    position_tiles = math.ceil(positions / (_IFSPAD_COLS if kind == "conv"
-                                            else 1))
+    position_tiles = math.ceil(positions / (_IFSPAD_COLS if spatial else 1))
     return (pipelines * macros, channel_tiles,
             channel_tiles * position_tiles * fan_in_tiles)
 
@@ -241,8 +148,8 @@ def chip_cost(cfg: dict, input_counts: np.ndarray, chunk_T: int):
     ``input_counts`` is the stream's (T, L) per-layer input-spike counts.
     """
     geometry = _layer_geometry(cfg)
-    maps = [_mapping(kind, f, k, p, cfg["weight_bits"])
-            for kind, f, k, p in geometry]
+    maps = [_mapping(spatial, f, k, p, cfg["weight_bits"])
+            for spatial, f, k, p in geometry]
     positions = np.array([f * p for _, f, _, p in geometry], np.float64)
     passes = sum(m[2] for m in maps)
     clocks = (np.zeros(_N_CM, np.int64), np.zeros(_N_CM, np.int64), 0)

@@ -4,7 +4,8 @@
     python3 bench/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
 The cell is an entry of ``workloads`` in ``BENCHMARK.json``; its
-configuration (``bench/configs/<name>.json``), traffic mix
+configuration (``bench/configs/<name>.json``, whose layers its network
+description ``bench/networks/<topology>.py`` walks), traffic mix
 (``bench/traffic/<name>.json``, whose clip pattern and arrival policy are
 ``bench/patterns/<name>.py`` and ``bench/arrivals/<name>.py``) and metric
 readers (``bench/metrics/<metric>.py``) are found by the names given
@@ -78,7 +79,9 @@ def load_benchmark(root: pathlib.Path) -> dict:
 
 def cell_plan(bench: dict, root: pathlib.Path, workload: str) -> dict:
     """Everything one cell names: its entry, configuration, traffic and
-    the metrics it reports, each found by the name in ``bench``."""
+    the metrics it reports, each found by the name in ``bench``.  The
+    configuration gains ``bench_root``, the tree whose network
+    description (``bench/networks/<topology>.py``) it is read with."""
     cells = {c["name"]: c for c in bench["workloads"]}
     if workload not in cells:
         raise KeyError(f"no workload {workload!r} in BENCHMARK.json "
@@ -86,6 +89,7 @@ def cell_plan(bench: dict, root: pathlib.Path, workload: str) -> dict:
     cell = cells[workload]
     configs = {c["name"]: c for c in bench["configs"]}
     cfg = json.loads((root / configs[cell["config"]]["file"]).read_text())
+    cfg["bench_root"] = str(root)
     traffic_file = root / "bench" / "traffic" / f"{cell['traffic']}.json"
     traffic = json.loads(traffic_file.read_text())
 
@@ -114,35 +118,14 @@ def _device_tag(devices) -> str:
 
 
 def program_spec(cfg: dict):
-    """The program's network for ``cfg``, checked layer by layer against
-    the configuration file, so the program runs the file as stated."""
+    """The program's network for ``cfg``, checked against the
+    configuration file by its network description, so the program runs
+    the file as stated."""
     module, attr = cfg["spec"].split(":")
     spec = getattr(importlib.import_module(module), attr)
     spec = dataclasses.replace(spec, input_hw=tuple(cfg["input_hw"]),
                                timesteps=cfg["timesteps"])
-    mismatch = []
-    if spec.in_channels != cfg["in_channels"] or \
-            spec.readout != cfg["readout"] or \
-            len(spec.layers) != len(cfg["layers"]):
-        mismatch.append("input channels, readout or depth")
-    n = cfg["neuron"]
-    for i, (sl, fl) in enumerate(zip(spec.layers, cfg["layers"])):
-        got = {"kind": sl.kind}
-        if sl.kind in ("conv", "fc"):
-            got.update(c_in=sl.c_in, c_out=sl.c_out)
-            neuron = sl.conv.neuron if sl.kind == "conv" else sl.fc.neuron
-            if (neuron.model, neuron.reset, neuron.threshold,
-                    neuron.leak_shift) != (n["model"], n["reset"],
-                                           n["threshold"], n["leak_shift"]):
-                mismatch.append(f"layer {i} neuron")
-        if sl.kind == "conv":
-            got.update(kh=sl.conv.kh, kw=sl.conv.kw, stride=sl.conv.stride,
-                       padding=sl.conv.padding)
-        if sl.kind == "adaptive_pool":
-            got.update(target_hw=sl.target_hw)
-        want = {k: v for k, v in fl.items() if k != "thr_int"}
-        if got != want:
-            mismatch.append(f"layer {i}: program {got}, file {want}")
+    mismatch = named.network(cfg).check_program(spec, cfg)
     if mismatch:
         raise ValueError(f"{cfg['spec']} does not match {cfg['name']}: "
                          + "; ".join(mismatch))
@@ -152,10 +135,11 @@ def program_spec(cfg: dict):
 def make_weights(cfg: dict):
     """Random integer weights from the configuration's weight seed, made
     on the device in one jitted call: ``(q, params)`` with ``q`` the int8
-    weights per weight layer, (F, K) with fan-in in (kh, kw, c_in) order,
-    and ``params`` the float32 weights the program is given, one per spec
-    layer (None for pools).  Each layer's float weights are ``q * s`` with
-    ``s = threshold / thr_int`` and one weight at the top level, so the
+    weights per weight layer, (F, K) with F the layer's ``fan_in`` (in
+    (kh, kw, c_in) order for a conv), and ``params`` what the program is
+    given per spec layer, as the network description makes it from the
+    float32 weights.  Each layer's float weights are ``q * s`` with ``s =
+    threshold / thr_int`` and one weight at the top level, so the
     program's per-tensor quantization recovers ``q`` and ``thr_int``.
 
     The weights follow the configuration, not the run's ``--seed``: the
@@ -174,10 +158,8 @@ def make_weights(cfg: dict):
         qs, ws = [], []
         for layer in layers:
             key, k = jax.random.split(key)
-            fan_in = (layer["kh"] * layer["kw"] * layer["c_in"]
-                      if layer["kind"] == "conv" else layer["c_in"])
-            q = jax.random.randint(k, (fan_in, layer["c_out"]), -levels,
-                                   levels + 1, jnp.int32)
+            q = jax.random.randint(k, (layer["fan_in"], layer["c_out"]),
+                                   -levels, levels + 1, jnp.int32)
             q = q.at[0, 0].set(levels)
             qs.append(q.astype(jnp.int8))
             ws.append(q.astype(jnp.float32)
@@ -185,10 +167,7 @@ def make_weights(cfg: dict):
         return qs, ws
 
     qs, ws = gen(jax.random.key(cfg["weights"]["seed"]))
-    it = iter(ws)
-    params = [next(it) if layer["kind"] in ("conv", "fc") else None
-              for layer in cfg["layers"]]
-    return qs, params
+    return qs, named.network(cfg).program_params(cfg, ws)
 
 
 class CompileLog:

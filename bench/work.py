@@ -1,15 +1,18 @@
 """The yardstick's operation and byte counts, and the table of peaks.
 
-Counts come from the configuration's layer shapes and stated bit widths
-only, never from the engine's tiling, padding or the tiles it skipped, so
-a change to how the work is done cannot move them:
+Counts come from the configuration's layer shapes, as its network
+description (``bench/networks/<topology>.py``) walks them, and stated bit
+widths only, never from the engine's tiling, padding or the tiles it
+skipped, so a change to how the work is done cannot move them:
 
 * operations: 2 x P x F x K per weight layer per frame (P output
   positions, F fan-in, K output channels), every frame of an active slot,
   dense;
 * bytes per slot and chunk: the chunk's events in at one byte per
   element, each layer's Vmem read and written once at
-  ceil(vmem_bits / 8) bytes, and the readout out at the same width;
+  ceil(vmem_bits / 8) bytes, any other state the network description
+  says a slot keeps (``extra_state_bytes``) read and written once, and
+  the readout out at the same width;
 * bytes per tick and replica: every weight once at
   ceil(weight_bits / 8) bytes.
 
@@ -22,6 +25,8 @@ import json
 import math
 import pathlib
 
+from . import named
+
 __all__ = ["bytes_per_slot_chunk", "layer_work", "least_seconds",
            "macs_per_frame", "peak_for", "weight_bytes"]
 
@@ -29,24 +34,8 @@ PEAKS_FILE = pathlib.Path(__file__).with_name("peaks.json")
 
 
 def layer_work(cfg: dict) -> list:
-    """(P, F, K) per weight layer, walking the configuration's shapes."""
-    h, w = cfg["input_hw"]
-    out = []
-    for layer in cfg["layers"]:
-        kind = layer["kind"]
-        if kind == "conv":
-            p, s = layer["padding"], layer["stride"]
-            h = (h + 2 * p - layer["kh"]) // s + 1
-            w = (w + 2 * p - layer["kw"]) // s + 1
-            out.append((h * w, layer["kh"] * layer["kw"] * layer["c_in"],
-                        layer["c_out"]))
-        elif kind == "fc":
-            out.append((1, layer["c_in"], layer["c_out"]))
-        elif kind == "pool":
-            h, w = h // 2, w // 2
-        elif kind == "adaptive_pool":
-            h = w = layer["target_hw"]
-    return out
+    """(P, F, K) per weight layer, as the network description counts it."""
+    return named.network(cfg).layer_work(cfg)
 
 
 def macs_per_frame(cfg: dict) -> int:
@@ -64,7 +53,9 @@ def bytes_per_slot_chunk(cfg: dict, chunk_T: int) -> int:
     vb = math.ceil(cfg["vmem_bits"] / 8)
     events = chunk_T * h * w * cfg["in_channels"]
     vmem = sum(2 * p * k * vb for p, _, k in layer_work(cfg))
-    return events + vmem + _readout_elements(cfg) * vb
+    extra = getattr(named.network(cfg), "extra_state_bytes", None)
+    kept = 2 * extra(cfg) if extra is not None else 0
+    return events + vmem + kept + _readout_elements(cfg) * vb
 
 
 def weight_bytes(cfg: dict) -> int:

@@ -4,7 +4,8 @@ A whole run of the harness is driven on the CPU at a test size (the look
 for a chip is skipped), once as it is and once with each fault a served
 cell can have planted in the timed path underneath it: a chunk step that
 returns its state unchanged, half of the slots left out of the step, and
-an answer altered where it is produced.  (The cells have no exchange
+an answer altered where it is produced; and, for four replicas, half
+of them left out of the fleet's tick.  (The cells have no exchange
 between chips: four-chip replicas never talk to each other.)  The
 control, the reference itself at one bit less of Vmem, has to differ
 from the reference on the same clips.
@@ -43,22 +44,25 @@ def jax_config():
 
 @pytest.fixture
 def tiny_root(tmp_path):
-    """A benchmark tree with two CPU-sized cells and the real readers."""
+    """A benchmark tree with CPU-sized cells and the real readers: both
+    one-chip cells, and four gesture replicas (on one CPU device)."""
     bench = json.loads((ROOT / "BENCHMARK.json").read_text())
     (tmp_path / "bench" / "traffic").mkdir(parents=True)
-    for kind in ("metrics", "patterns", "arrivals"):
+    for kind in ("metrics", "patterns", "arrivals", "networks"):
         (tmp_path / "bench" / kind).symlink_to(ROOT / "bench" / kind)
     (tmp_path / "src").symlink_to(ROOT / "src")
-    cells = {"tiny-poisson": ("tiny-gesture", "poisson-clips", 16),
-             "tiny-saturated": ("tiny-optflow", "saturated-clips", 4)}
+    cells = {"tiny-poisson": ("tiny-gesture", "poisson-clips", 16, 1),
+             "tiny-saturated": ("tiny-optflow", "saturated-clips", 4, 1),
+             "tiny-poisson-x4": ("tiny-gesture", "poisson-clips-x4", 16, 4)}
     bench["configs"] = []
     bench["workloads"] = []
-    for cell, (config, traffic, clips) in cells.items():
-        bench["configs"].append({"name": config, "source": "test",
-                                 "file": f"{config}.json", "reduced": [],
-                                 "why": "test"})
-        (tmp_path / f"{config}.json").write_text(
-            (DATA / f"{config}.json").read_text())
+    for cell, (config, traffic, clips, chips) in cells.items():
+        if not (tmp_path / f"{config}.json").exists():
+            bench["configs"].append({"name": config, "source": "test",
+                                     "file": f"{config}.json",
+                                     "reduced": [], "why": "test"})
+            (tmp_path / f"{config}.json").write_text(
+                (DATA / f"{config}.json").read_text())
         t = json.loads((ROOT / "bench" / "traffic" / f"{traffic}.json")
                        .read_text())
         t["pool"]["clips"] = clips
@@ -66,12 +70,12 @@ def tiny_root(tmp_path):
         (tmp_path / "bench" / "traffic" / f"{cell}.json").write_text(
             json.dumps(t))
         bench["workloads"].append({"name": cell, "config": config,
-                                   "traffic": cell, "chips": 1,
+                                   "traffic": cell, "chips": chips,
                                    "why": "test"})
     for m in bench["end_to_end"] + bench["per_layer"]:
         if "workloads" in m:
             open_ = any("poisson" in w for w in m["workloads"])
-            m["workloads"] = ["tiny-poisson" if open_ else "tiny-saturated"]
+            m["workloads"] = [c for c in cells if ("poisson" in c) == open_]
     (tmp_path / "BENCHMARK.json").write_text(json.dumps(bench))
     return tmp_path
 
@@ -83,7 +87,7 @@ def _run(root, cell, seed=2**35 + 11, weights_seed=None):
 
 @pytest.mark.parametrize("cell,weights_seed", [
     ("tiny-poisson", None), ("tiny-saturated", None),
-    ("tiny-poisson", 2**33 + 5)])
+    ("tiny-poisson", 2**33 + 5), ("tiny-poisson-x4", None)])
 def test_a_sound_run_is_correct(tiny_root, jax_config, cell, weights_seed):
     result = _run(tiny_root, cell, weights_seed=weights_seed)
     assert result["correct"], result["checks"]
@@ -131,7 +135,8 @@ def _altered_answer(monkeypatch):
     monkeypatch.setattr(streaming.StreamSessionManager, "step", altered)
 
 
-@pytest.mark.parametrize("cell", ["tiny-poisson", "tiny-saturated"])
+@pytest.mark.parametrize("cell", ["tiny-poisson", "tiny-saturated",
+                                  "tiny-poisson-x4"])
 @pytest.mark.parametrize("fault", [_stale_state, _half_the_slots,
                                    _altered_answer])
 def test_a_broken_timed_path_is_not_correct(tiny_root, jax_config,
@@ -142,10 +147,35 @@ def test_a_broken_timed_path_is_not_correct(tiny_root, jax_config,
     assert result["checks"]["readout_mismatch"]["value"] > 0
 
 
+def test_replicas_left_out_of_the_tick_are_not_correct(tiny_root,
+                                                       jax_config,
+                                                       monkeypatch):
+    """Half of the four replicas never tick: the clips placed on them are
+    never answered."""
+    from repro.serving import fleet as fleet_mod
+
+    real = fleet_mod.Fleet.step
+
+    def half(self):
+        workers = self.workers
+        self.workers = workers[: len(workers) // 2]
+        try:
+            return real(self)
+        finally:
+            self.workers = workers
+
+    monkeypatch.setattr(fleet_mod.Fleet, "step", half)
+    monkeypatch.setattr(run, "DRAIN_S", 2.0)
+    result = _run(tiny_root, "tiny-poisson-x4")
+    assert not result["correct"]
+    assert result["checks"]["unanswered"]["value"] > 0
+
+
 @pytest.mark.parametrize("config,traffic", [
     ("tiny-gesture", "poisson-clips"), ("tiny-optflow", "saturated-clips")])
 def test_the_control_at_one_bit_less_of_vmem_fails(config, traffic):
-    cfg = json.loads((DATA / f"{config}.json").read_text())
+    cfg = dict(json.loads((DATA / f"{config}.json").read_text()),
+               bench_root=str(ROOT))
     t = json.loads((ROOT / "bench" / "traffic" / f"{traffic}.json")
                    .read_text())
     t["pool"]["clips"] = 8

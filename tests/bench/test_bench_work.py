@@ -12,7 +12,10 @@ from bench import work  # noqa: E402
 
 
 def _cfg(name):
-    return json.loads((ROOT / "bench" / "configs" / f"{name}.json").read_text())
+    """The configuration file with the tree its network description is
+    read from, as ``bench.run.cell_plan`` reads it."""
+    return dict(json.loads((ROOT / "bench" / "configs" / f"{name}.json")
+                           .read_text()), bench_root=str(ROOT))
 
 
 def test_optical_flow_macs_per_frame_by_hand():
