@@ -13,10 +13,11 @@ plus the size of the spike plane they consume per timestep
 (``in_positions`` — the routing-volume proxy: at input density ``d`` the
 layer receives ``d * in_positions`` spikes per timestep).
 
-The IR is deliberately a chain with explicit predecessor links rather than
-a general DAG: both paper networks are chains, but everything downstream
-(partitioner, router) only uses ``inputs``/``consumers``, so branching
-topologies are an IR extension, not a rewrite.
+Nodes are in execution order, each linked to the node before it: the
+partitioner and router place and route a chain.  A recurrent conv also
+consumes its own previous spikes, so its ``in_positions`` counts its
+output plane too; its self-loop is not an edge here, since only the
+single-core engine runs recurrent layers (``compile_engine`` refuses them).
 """
 from __future__ import annotations
 
@@ -33,11 +34,13 @@ class LayerNode:
     """One spec layer as a graph node.
 
     ``idx``          position in ``spec.layers`` (== params index).
-    ``kind``         "conv" | "fc" | "pool" | "adaptive_pool".
+    ``kind``         "conv" (recurrent and stateless ones too) | "fc" |
+                     "pool" | "adaptive_pool".
     ``shape``        accelerator-view :class:`LayerShape` (weight nodes only).
     ``inputs``       predecessor node indices (empty for the input layer).
     ``in_positions`` spike-plane positions consumed per timestep
-                     (H*W*C_in for conv, N_in for fc) — routing volume.
+                     (H*W*C_in for conv, plus H*W*C_out for a
+                     recurrent one; N_in for fc) — routing volume.
     ``out_positions``spike-plane positions produced per timestep.
     """
 
@@ -91,6 +94,8 @@ def build_graph(spec: SNNSpec) -> NetworkGraph:
             h = (h + 2 * p.padding - p.kh) // p.stride + 1
             w = (w + 2 * p.padding - p.kw) // p.stride + 1
             c = l.c_out
+            if l.recurrent:
+                in_pos += h * w * c
             nodes.append(LayerNode(i, "conv", shape, inputs,
                                    in_positions=in_pos,
                                    out_positions=h * w * c))

@@ -1,4 +1,4 @@
-"""The paper's two evaluation networks (Table II) as JAX SNNs.
+"""Spiking networks as layer lists (``SNNSpec``), and the paper's two.
 
   Optical flow estimation : input 288x384x2, 10 timesteps,
       Conv(2,32) + 6x Conv(32,32) + Conv(32,2)      (3x3, stride 1, pad 1)
@@ -8,10 +8,22 @@
       adaptive 2x2 pool before the FC so N_in = 16ch * 2 * 2 = 64.
 
 Kernel sizes are not given in the paper; 3x3/stride-1/pad-1 is assumed
-(standard for both reference tasks) — recorded in DESIGN.md §7.  The
-networks are pure functions over a params pytree and scan over timesteps;
-the same definition runs in float-QAT training mode and bit-exact integer
-inference mode.
+(standard for both reference tasks) — recorded in DESIGN.md §7.
+
+A spec is a list of layers, each fed by the plane the one before it passes
+on.  Two conv-layer flags reach beyond a chain (LIF-FireNet,
+``configs/lif_firenet.py``, uses both):
+
+  * ``recurrent``: the layer convolves the channel concatenation of its
+    input and its own spikes of the previous timestep (zero at t = 0);
+    its weights are ``(kh, kw, c_in + c_out, c_out)``, input channels first;
+  * ``stateless`` (the last layer only): no neuron and no Vmem; its
+    saturated integer current is what a ``"vmem"`` readout reads (a
+    regression head).
+
+The paper's chain networks run in float-QAT training mode and in the
+bit-exact integer engine; a spec with any of the flags runs in the integer
+engine only (training and export refuse it).
 """
 from __future__ import annotations
 
@@ -45,6 +57,24 @@ class SNNLayer:
     conv: SpikingConvParams | None = None
     fc: SpikingDenseParams | None = None
     target_hw: int = 0  # adaptive pool target
+    # Beyond a chain (conv layers only; see the module docstring).
+    recurrent: bool = False       # input joined to own previous spikes
+    stateless: bool = False       # no neuron: the readout is its current
+
+    @property
+    def fan_in(self) -> int:
+        """Weight rows: kh*kw*(c_in [+ c_out if recurrent]) or N_in."""
+        if self.kind != "conv":
+            return self.c_in
+        c = self.c_in + (self.c_out if self.recurrent else 0)
+        return self.conv.kh * self.conv.kw * c
+
+    @property
+    def extension(self) -> str | None:
+        """What makes this layer more than a chain layer, or None."""
+        parts = (["recurrent"] if self.recurrent else []) + (
+            ["stateless"] if self.stateless else [])
+        return ", ".join(parts) or None
 
 
 @dataclasses.dataclass(frozen=True)
@@ -56,23 +86,74 @@ class SNNSpec:
     layers: tuple
     readout: str  # "rate" (classification) or "vmem" (regression/flow)
 
-    def layer_shapes(self) -> list:
-        """Accelerator-view shapes per weight layer (for modes/energy)."""
+    def __post_init__(self):
+        planes = self.planes()
+        for i, l in enumerate(self.layers):
+            if l.extension is None:
+                continue
+            if l.kind != "conv":
+                raise ValueError(f"layer {i} ({l.kind}): only conv layers "
+                                 f"can be {l.extension}")
+            if l.stateless and i != len(self.layers) - 1:
+                raise ValueError(f"layer {i}: only the last layer can be "
+                                 "stateless")
+            h, w = planes[i - 1][:2] if i else self.input_hw
+            if l.recurrent and planes[i][:2] != (h, w):
+                raise ValueError(
+                    f"layer {i}: a recurrent conv must keep its input's "
+                    f"{h}x{w} plane to join its own spikes, not "
+                    f"{planes[i][0]}x{planes[i][1]}")
+
+    def planes(self) -> list:
+        """The shape of the plane each layer passes on: (H, W, C) after a
+        conv or pool, (N,) after an fc."""
         h, w = self.input_hw
+        c = self.in_channels
         out = []
         for l in self.layers:
             if l.kind == "conv":
                 p = l.conv
-                h_out = (h + 2 * p.padding - p.kh) // p.stride + 1
-                w_out = (w + 2 * p.padding - p.kw) // p.stride + 1
-                out.append(LayerShape.conv(p.kh, p.kw, l.c_in, l.c_out, h_out, w_out))
-                h, w = h_out, w_out
-            elif l.kind == "fc":
-                out.append(LayerShape.fc(l.c_in, l.c_out))
+                h = (h + 2 * p.padding - p.kh) // p.stride + 1
+                w = (w + 2 * p.padding - p.kw) // p.stride + 1
+                c = l.c_out
             elif l.kind == "pool":
                 h, w = h // 2, w // 2
             elif l.kind == "adaptive_pool":
                 h = w = l.target_hw
+            if l.kind == "fc":
+                out.append((l.c_out,))
+            else:
+                out.append((h, w, c))
+        return out
+
+    def require_chain(self, what: str) -> None:
+        """Raise a clear error naming the first layer that is not a chain
+        layer: ``what`` (training, export, ...) runs chains only."""
+        for i, l in enumerate(self.layers):
+            if l.extension is not None:
+                raise ValueError(
+                    f"{what} supports chain networks only; {self.name} "
+                    f"layer {i} is {l.extension}")
+
+    def readout_shape(self, batch: int) -> tuple:
+        """The readout: summed last-layer spikes ``(B, classes)``
+        ("rate"), or the last weight layer's output plane ("vmem": its
+        Vmem, or a stateless head's current)."""
+        last = next(i for i in reversed(range(len(self.layers)))
+                    if self.layers[i].kind in ("conv", "fc"))
+        if self.readout == "rate":
+            return (batch, self.layers[last].c_out)
+        return (batch,) + self.planes()[last]
+
+    def layer_shapes(self) -> list:
+        """Accelerator-view shapes per weight layer (for modes/energy)."""
+        out = []
+        for l, plane in zip(self.layers, self.planes()):
+            if l.kind == "conv":
+                out.append(LayerShape("conv", l.fan_in, l.c_out,
+                                      plane[0] * plane[1]))
+            elif l.kind == "fc":
+                out.append(LayerShape.fc(l.c_in, l.c_out))
         return out
 
 
@@ -133,7 +214,9 @@ def init_params(key: jax.Array, spec: SNNSpec) -> list:
     for l in spec.layers:
         if l.kind == "conv":
             key, k = jax.random.split(key)
-            params.append(init_conv(k, l.conv.kh, l.conv.kw, l.c_in, l.c_out))
+            params.append(init_conv(k, l.conv.kh, l.conv.kw,
+                                    l.fan_in // (l.conv.kh * l.conv.kw),
+                                    l.c_out))
         elif l.kind == "fc":
             key, k = jax.random.split(key)
             params.append(init_dense(k, l.c_in, l.c_out))
@@ -143,24 +226,19 @@ def init_params(key: jax.Array, spec: SNNSpec) -> list:
 
 
 def _init_state(spec: SNNSpec, batch: int):
-    """Vmem carries for every stateful layer."""
-    h, w = spec.input_hw
-    states = []
-    for l in spec.layers:
-        if l.kind == "conv":
-            p = l.conv
-            h = (h + 2 * p.padding - p.kh) // p.stride + 1
-            w = (w + 2 * p.padding - p.kw) // p.stride + 1
-            states.append(jnp.zeros((batch, h, w, l.c_out)))
-        elif l.kind == "fc":
-            states.append(jnp.zeros((batch, l.c_out)))
-        elif l.kind == "pool":
-            h, w = h // 2, w // 2
-            states.append(None)
-        elif l.kind == "adaptive_pool":
-            h = w = l.target_hw
-            states.append(None)
-    return states
+    """Vmem carries for every stateful layer (None for pools and
+    stateless layers)."""
+    return [jnp.zeros((batch,) + plane)
+            if l.kind in ("conv", "fc") and not l.stateless else None
+            for l, plane in zip(spec.layers, spec.planes())]
+
+
+def _init_spikes(spec: SNNSpec, batch: int) -> tuple:
+    """Each recurrent layer's previous spike plane, int8 zeros, in layer
+    order (empty for a chain)."""
+    return tuple(jnp.zeros((batch,) + plane, jnp.int8)
+                 for l, plane in zip(spec.layers, spec.planes())
+                 if l.recurrent)
 
 
 def _forward_t(
@@ -217,6 +295,7 @@ def run_snn(
     plus per-layer total spike counts if ``record_spikes`` (for the
     sparsity profile of Fig 5 and the energy model).
     """
+    spec.require_chain("float / QAT simulation (run_snn)")
     batch = inputs.shape[1]
     state0 = _init_state(spec, batch)
 

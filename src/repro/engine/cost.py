@@ -13,7 +13,9 @@ The mapping from spikes to compute-macro cycles follows Sec II-E/II-F:
 each input spike of a weight layer triggers 2 row operations (even+odd
 Vmem rows) per weight-stationary channel tile; rows are balanced across
 the 9 compute macros, so per-macro cycles are the layer total divided by
-the macros in the layer's pipeline configuration.
+the macros in the layer's pipeline configuration.  A recurrent layer's
+fan-in counts its own previous spikes (``SNNLayer.fan_in``) and so does
+its input count.
 
 ``estimate_multicore_cost`` extends the same row-op model to a compiled
 ``repro.compiler`` CoreSchedule: one async-handshake simulation per core

@@ -12,6 +12,13 @@ modeled one macro drain / one GEMM at a time; the engine closes the loop:
       pool : maxpool on the spike plane (binary in, binary out)
   readout: summed output spikes ("rate") or final-layer Vmem ("vmem")
 
+Beyond a chain (``core.network``'s layer flags; only this scan walk runs
+them): a recurrent conv joins its own previous spikes (carried in
+``EngineState.spikes``) to its input before im2col, so one GEMM of fan-in
+``kh*kw*(c_in + c_out)`` takes both; a stateless head runs the same
+update from a zero Vmem with a threshold it cannot reach, so its output is
+its saturated current and it carries no state.
+
 Execution modes:
   * backend="fused" — the Pallas ``fused_lif_gemm_int`` kernel with
     tile-level zero-skipping (``interpret=True`` on CPU).
@@ -74,7 +81,7 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from ..compiler.schedule import CoreSchedule
 from ..core.layers import im2col, maxpool2d
-from ..core.network import SNNSpec
+from ..core.network import SNNSpec, _init_spikes, _init_state
 from ..core.neuron import NeuronConfig, neuron_step_int
 from ..core.quant import QuantSpec, quantize, saturate
 from ..kernels.fused_lif_gemm import (
@@ -184,6 +191,9 @@ class EngineState:
                     the last weight layer's Vmem ("vmem").
     ``out_counts``  ``(n_weight_layers, B)`` cumulative output spikes.
     ``in_counts``   ``(n_weight_layers, B)`` cumulative input spikes.
+    ``spikes``      each recurrent layer's int8 spike plane of the last
+                    timestep, ``(B, H, W, C)``, in layer order: the input
+                    it joins to its next timestep's (empty for a chain).
 
     All accumulators are int32, like the rest of the integer datapath: a
     persistent "rate" stream wraps once any output unit or counter passes
@@ -196,6 +206,7 @@ class EngineState:
     readout_acc: jax.Array
     out_counts: jax.Array
     in_counts: jax.Array
+    spikes: tuple = ()
 
 
 @dataclasses.dataclass
@@ -509,6 +520,7 @@ def _run_chunk_tiled(engine: SNNEngine, state: EngineState,
     remains the right tool for huge single-chunk runs).
     """
     spec = engine.spec
+    spec.require_chain("the layer-outer t_block walk (t_block > 1)")
     t, b = events.shape[:2]
     act = events.astype(jnp.float32)
     new_vmem, counts_out, counts_in = [], [], []
@@ -601,6 +613,7 @@ def compile_engine(engine: SNNEngine, schedule: CoreSchedule,
 def _compile_engine(engine: SNNEngine, schedule: CoreSchedule,
                     device_parallel: Optional[bool] = None) -> SNNEngine:
     assert engine.schedule is None, "engine already carries a schedule"
+    engine.spec.require_chain("the multi-core path (n_cores > 1)")
     for ls in schedule.layers:
         if ls.plan.spec != engine.cfg.qspec:
             raise ValueError(
@@ -663,19 +676,29 @@ def _layer_scopes(engine: SNNEngine) -> list:
     return names
 
 
-def _forward_t(engine: SNNEngine, state, x_t):
+def _forward_t(engine: SNNEngine, state, x_t, spikes=()):
     """One timestep through every layer.
 
-    Returns ``(state', out, counts_out, counts_in)`` with *per-sample*
-    counts of shape ``(n_weight_layers, B)`` — the batch axis is kept so a
-    streaming session can attribute spikes (and therefore chip cost) to the
+    ``spikes`` are the recurrent layers' previous spike planes
+    (``EngineState.spikes``).  Returns ``(state', spikes', out,
+    counts_out, counts_in)`` with *per-sample* counts of shape
+    ``(n_weight_layers, B)`` — the batch axis is kept so a streaming
+    session can attribute spikes (and therefore chip cost) to the
     individual stream occupying each batch slot.
     """
+    spec = engine.spec
+    shapes = spec.planes()
     act = x_t  # float {0,1} spike plane (im2col needs float)
-    new_state, counts_out, counts_in, out = [], [], [], None
-    for el, v, scope in zip(engine.layers, state, _layer_scopes(engine)):
+    prev = iter(spikes)
+    new_state, new_spikes, counts_out, counts_in, out = [], [], [], [], None
+    for i, (el, layer, v, scope) in enumerate(zip(
+            engine.layers, spec.layers, state, _layer_scopes(engine))):
         if el.kind == "conv":
             b = act.shape[0]
+            if layer.recurrent:
+                with jax.named_scope(f"{scope}.recur"):
+                    act = jnp.concatenate(
+                        [act, next(prev).astype(jnp.float32)], axis=-1)
             with jax.named_scope(f"{scope}.counts"):
                 counts_in.append(jnp.sum(act != 0, axis=(1, 2, 3)))
             with jax.named_scope(f"{scope}.patches"):
@@ -683,13 +706,22 @@ def _forward_t(engine: SNNEngine, state, x_t):
                 rows, f = b * cols.shape[1], cols.shape[2]   # (B*P, F)
                 k = el.w_q.shape[1]
                 s2 = cols.reshape(rows, f).astype(jnp.int8)
+            plane = (b,) + shapes[i]
             with jax.named_scope(f"{scope}.kernel"):
-                v_next, s = _layer_update(engine, el, s2, v.reshape(rows, k))
-                v_next = v_next.reshape(v.shape)
-                s = s.reshape(v.shape)
-            new_state.append(v_next)
+                if layer.stateless:
+                    v_next, s = _fused_update(
+                        el, s2, jnp.zeros((rows, k), jnp.int32), engine.cfg,
+                        thr=engine.cfg.qspec.v_max + 1)
+                else:
+                    v_next, s = _layer_update(engine, el, s2,
+                                              v.reshape(rows, k))
+                v_next = v_next.reshape(plane)
+                s = s.reshape(plane)
+            new_state.append(None if layer.stateless else v_next)
             with jax.named_scope(f"{scope}.counts"):
                 counts_out.append(jnp.sum(s, axis=(1, 2, 3)))
+            if layer.recurrent:
+                new_spikes.append(s.astype(jnp.int8))
             act, out = s.astype(jnp.float32), (v_next, s)
         elif el.kind == "fc":
             with jax.named_scope(f"{scope}.patches"):
@@ -713,16 +745,15 @@ def _forward_t(engine: SNNEngine, state, x_t):
             with jax.named_scope(scope):
                 act = maxpool2d(act, window=kk, stride=kk)
             new_state.append(None)
-    return new_state, out, jnp.stack(counts_out), jnp.stack(counts_in)
+    return (new_state, tuple(new_spikes), out, jnp.stack(counts_out),
+            jnp.stack(counts_in))
 
 
 def _init_vmem(engine: SNNEngine, batch: int):
     """Integer Vmem carries (network's float shape walk, cast to int32)."""
-    from ..core.network import _init_state as _float_state
-
     return [
         None if s is None else s.astype(jnp.int32)
-        for s in _float_state(engine.spec, batch)
+        for s in _init_state(engine.spec, batch)
     ]
 
 
@@ -733,20 +764,15 @@ def _n_weight_layers(engine: SNNEngine) -> int:
 def init_state(engine: SNNEngine, batch: int) -> EngineState:
     """Fresh (all-zero) persistent state for ``batch`` concurrent streams."""
     spec = engine.spec
-    vmem = _init_vmem(engine, batch)
-    if spec.readout == "rate":
-        acc0 = jnp.zeros((batch, spec.layers[-1].c_out), jnp.int32)
-    else:
-        # Vmem readout: the accumulator is the last weight layer's Vmem,
-        # whose spatial shape reflects any pooling/striding along the way.
-        acc0 = jnp.zeros_like(
-            next(s for s in reversed(vmem) if s is not None))
     n_l = _n_weight_layers(engine)
     return EngineState(
-        vmem=tuple(vmem),
-        readout_acc=acc0,
+        vmem=tuple(_init_vmem(engine, batch)),
+        # The last weight layer's plane (any pooling/striding along the
+        # way) for a "vmem" readout, its classes for "rate".
+        readout_acc=jnp.zeros(spec.readout_shape(batch), jnp.int32),
         out_counts=jnp.zeros((n_l, batch), jnp.int32),
         in_counts=jnp.zeros((n_l, batch), jnp.int32),
+        spikes=_init_spikes(spec, batch),
     )
 
 
@@ -754,9 +780,10 @@ def reset_slot(state: EngineState, slot) -> EngineState:
     """Zero one batch slot's state, leaving every other slot untouched.
 
     This is slot retirement for continuous batching: the retired stream's
-    Vmem, readout and counters are cleared so the next stream admitted into
-    the slot starts from ``init_state`` conditions, and so the slot's
-    all-zero spike planes feed the zero-skip path until then.  ``slot`` may
+    Vmem, previous spikes, readout and counters are cleared so the next
+    stream admitted into the slot starts from ``init_state`` conditions,
+    and so the slot's all-zero spike planes feed the zero-skip path until
+    then.  ``slot`` may
     be a traced int32 — the update is a pure scatter, safe under ``jit``.
     """
     return EngineState(
@@ -765,6 +792,7 @@ def reset_slot(state: EngineState, slot) -> EngineState:
         readout_acc=state.readout_acc.at[slot].set(0),
         out_counts=state.out_counts.at[:, slot].set(0),
         in_counts=state.in_counts.at[:, slot].set(0),
+        spikes=tuple(z.at[slot].set(0) for z in state.spikes),
     )
 
 
@@ -791,22 +819,24 @@ def run_chunk(
                                 collect_counts, collect_readouts)
 
     def step(carry, x_t):
-        vmem, acc, oc, ic = carry
-        vmem, (v, s), c_out, c_in = _forward_t(engine, list(vmem), x_t)
+        vmem, spikes, acc, oc, ic = carry
+        vmem, spikes, (v, s), c_out, c_in = _forward_t(
+            engine, list(vmem), x_t, spikes)
         with jax.named_scope("spidr.readout"):
             acc = acc + s if spec.readout == "rate" else v
-        carry = (tuple(vmem), acc, oc + c_out, ic + c_in)
+        carry = (tuple(vmem), spikes, acc, oc + c_out, ic + c_in)
         ys = (
             (c_out, c_in) if collect_counts else None,
             acc if collect_readouts else None,
         )
         return carry, ys
 
-    carry0 = (state.vmem, state.readout_acc, state.out_counts,
-              state.in_counts)
-    (vmem, acc, oc, ic), (counts, accs) = jax.lax.scan(step, carry0, events)
+    carry0 = (state.vmem, state.spikes, state.readout_acc,
+              state.out_counts, state.in_counts)
+    (vmem, spikes, acc, oc, ic), (counts, accs) = jax.lax.scan(
+        step, carry0, events)
     new_state = EngineState(vmem=vmem, readout_acc=acc,
-                            out_counts=oc, in_counts=ic)
+                            out_counts=oc, in_counts=ic, spikes=spikes)
     slot_out = slot_in = None
     sum_out = sum_in = None
     if collect_counts:
@@ -865,7 +895,8 @@ jax.tree_util.register_pytree_node(
 
 jax.tree_util.register_pytree_node(
     EngineState,
-    lambda st: ((st.vmem, st.readout_acc, st.out_counts, st.in_counts), None),
+    lambda st: ((st.vmem, st.readout_acc, st.out_counts, st.in_counts,
+                 st.spikes), None),
     lambda _, leaves: EngineState(*leaves),
 )
 
@@ -888,10 +919,12 @@ def run_reference(engine: SNNEngine, events) -> EngineOutput:
     ref_engine = dataclasses.replace(engine, cfg=cfg)
     batch = events.shape[1]
     state = _init_vmem(ref_engine, batch)
+    spikes = _init_spikes(spec, batch)
     acc = None
     all_out, all_in = [], []
     for t in range(events.shape[0]):
-        state, (v, s), c_out, c_in = _forward_t(ref_engine, state, events[t])
+        state, spikes, (v, s), c_out, c_in = _forward_t(
+            ref_engine, state, events[t], spikes)
         if spec.readout == "rate":
             acc = s if acc is None else acc + s
         else:

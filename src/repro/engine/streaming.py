@@ -523,15 +523,19 @@ class StreamSessionManager:
         snapshot boundary, chunking and slot open/close interleaving).
         """
         st = self.state
+        engine_state = {
+            "vmem": [None if v is None else np.asarray(v).copy()
+                     for v in st.vmem],
+            "readout_acc": np.asarray(st.readout_acc).copy(),
+            "out_counts": np.asarray(st.out_counts).copy(),
+            "in_counts": np.asarray(st.in_counts).copy(),
+        }
+        if st.spikes:   # recurrent layers only: a chain's tree is unchanged
+            engine_state["spikes"] = [np.asarray(z).copy()
+                                      for z in st.spikes]
         return {
             "schema": np.int64(SESSION_SCHEMA_VERSION),
-            "engine_state": {
-                "vmem": [None if v is None else np.asarray(v).copy()
-                         for v in st.vmem],
-                "readout_acc": np.asarray(st.readout_acc).copy(),
-                "out_counts": np.asarray(st.out_counts).copy(),
-                "in_counts": np.asarray(st.in_counts).copy(),
-            },
+            "engine_state": engine_state,
             "table": {
                 "active": np.asarray(self.active, np.bool_),
                 "ended": np.asarray(self.ended, np.bool_),
@@ -585,9 +589,11 @@ class StreamSessionManager:
                     "layers — restore onto the same network/spec")
             vmem.append(None if new is None
                         else jnp.asarray(new, jnp.int32))
+        spikes = self._spikes_of(es)
         self.state = dataclasses.replace(
             self.state,
             vmem=tuple(vmem),
+            spikes=tuple(jnp.asarray(z, jnp.int8) for z in spikes),
             readout_acc=jnp.asarray(es["readout_acc"],
                                     self.state.readout_acc.dtype),
             out_counts=jnp.asarray(es["out_counts"], jnp.int32),
@@ -615,6 +621,20 @@ class StreamSessionManager:
             pipe.append(states if self._schedule is not None else states[0])
         self._pipe_state = pipe
 
+    def _spikes_of(self, tree: dict, per_slot: bool = False) -> list:
+        """The previous-spike planes a snapshot or slot payload carries,
+        checked against this engine's recurrent layers (none, and no key,
+        for a chain)."""
+        got = list(tree.get("spikes", []))
+        want = [z.shape[1:] if per_slot else z.shape
+                for z in self.state.spikes]
+        if [np.shape(z) for z in got] != want:
+            raise ValueError(
+                f"previous-spike planes {[np.shape(z) for z in got]} do not "
+                f"match this engine's recurrent layers {want} — restore onto "
+                "the same network/spec")
+        return got
+
     # -- live migration: one slot's durable state --------------------------
     def export_slot(self, slot: int) -> dict:
         """One live stream's complete durable state as a pure-numpy tree.
@@ -633,7 +653,7 @@ class StreamSessionManager:
                 f"slot {slot} is not active — only a live stream's state "
                 "can be exported for migration")
         st = self.state
-        return {
+        payload = {
             "schema": np.int64(SESSION_SCHEMA_VERSION),
             "vmem": [None if v is None else np.asarray(v[slot]).copy()
                      for v in st.vmem],
@@ -652,6 +672,10 @@ class StreamSessionManager:
             },
             "clocks": self._pipe_dicts(slot),
         }
+        if st.spikes:   # recurrent layers only: a chain's payload is unchanged
+            payload["spikes"] = [np.asarray(z[slot]).copy()
+                                 for z in st.spikes]
+        return payload
 
     def import_slot(self, payload: dict, slot: Optional[int] = None) -> int:
         """Install an :meth:`export_slot` payload into a free slot.
@@ -692,6 +716,7 @@ class StreamSessionManager:
                     "slot payload Vmem shapes do not match this engine's "
                     "layers — migrate between replicas of the same "
                     "network/spec")
+        spikes = self._spikes_of(payload, per_slot=True)
         vmem = tuple(
             cur if cur is None
             else cur.at[slot].set(jnp.asarray(new, jnp.int32))
@@ -699,6 +724,8 @@ class StreamSessionManager:
         self.state = dataclasses.replace(
             st,
             vmem=vmem,
+            spikes=tuple(cur.at[slot].set(jnp.asarray(new, jnp.int8))
+                         for cur, new in zip(st.spikes, spikes)),
             readout_acc=st.readout_acc.at[slot].set(
                 jnp.asarray(payload["readout_acc"],
                             st.readout_acc.dtype)),

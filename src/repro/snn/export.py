@@ -100,7 +100,10 @@ def export_network(params, spec: SNNSpec, qspec: QuantSpec) -> ExportedNetwork:
     of the weights (the same ``po2_quantize`` the QAT forward ran, so the
     integers are identical to what training saw through the STE), and the
     float firing threshold requantized onto the layer's integer Vmem grid.
+    Chain networks only: a spec with a recurrent or stateless
+    layer raises ``ValueError`` naming the layer.
     """
+    spec.require_chain("snn.export")
     layers = []
     for layer, p in zip(spec.layers, params):
         if layer.kind not in ("conv", "fc"):
@@ -133,6 +136,7 @@ def deploy(
     is bit-exact with single-core execution under any chunking.  ``cfg``
     defaults to the pure-jnp backend at the exported precision.
     """
+    spec.require_chain("snn.export.deploy")
     cfg = cfg or EngineConfig(exported.qspec, backend="jnp")
     if cfg.qspec.weight_bits != exported.weight_bits:
         raise ValueError(
@@ -242,6 +246,7 @@ def load_exported(ckpt: Checkpointer, spec: SNNSpec,
     ``spec``'s layer structure; missing leaf files surface as
     ``FileNotFoundError`` from the checkpointer.
     """
+    spec.require_chain("snn.export.load_exported")
     if step is None:
         step = ckpt.latest_step()
         if step is None:

@@ -107,6 +107,7 @@ class TrainState:
 
 
 def init_train_state(key, spec: SNNSpec, cfg: TrainConfig) -> TrainState:
+    spec.require_chain("snn.train")
     params = init_params(key, spec)
     _, opt_state = adamw(lr=cfg.lr, weight_decay=cfg.weight_decay, params=params)
     return TrainState(params=params, opt_state=opt_state, step=0)

@@ -938,13 +938,10 @@ def _layer_arrays_template(spec: SNNSpec, per_channel: bool) -> list:
     """
     like = []
     for layer in spec.layers:
-        if layer.kind == "conv":
-            f, k = layer.conv.kh * layer.conv.kw * layer.c_in, layer.c_out
-        elif layer.kind == "fc":
-            f, k = layer.c_in, layer.c_out
-        else:
+        if layer.kind not in ("conv", "fc"):
             like.append(None)
             continue
+        f, k = layer.fan_in, layer.c_out
         sshape = (k,) if per_channel else ()
         like.append({"w_q": np.zeros((f, k), np.int8),
                      "w_scale": np.zeros(sshape, np.float64),
@@ -961,24 +958,23 @@ def _session_state_template(spec: SNNSpec, capacity: int,
     tree before any engine exists — the weights themselves are part of the
     same checkpoint being restored.
     """
-    from ..core.network import _init_state
+    from ..core.network import _init_spikes, _init_state
 
     vmem = [None if v is None else np.zeros(v.shape, np.int32)
             for v in _init_state(spec, capacity)]
-    if spec.readout == "rate":
-        acc = np.zeros((capacity, spec.layers[-1].c_out), np.int32)
-    else:
-        acc = np.zeros(next(v for v in reversed(vmem)
-                            if v is not None).shape, np.int32)
     n_l = sum(1 for layer in spec.layers if layer.kind in ("conv", "fc"))
+    engine_state = {
+        "vmem": vmem,
+        "readout_acc": np.zeros(spec.readout_shape(capacity), np.int32),
+        "out_counts": np.zeros((n_l, capacity), np.int32),
+        "in_counts": np.zeros((n_l, capacity), np.int32),
+    }
+    spikes = _init_spikes(spec, capacity)
+    if spikes:
+        engine_state["spikes"] = [np.zeros(z.shape, np.int8) for z in spikes]
     return {
         "schema": np.int64(SESSION_SCHEMA_VERSION),
-        "engine_state": {
-            "vmem": vmem,
-            "readout_acc": acc,
-            "out_counts": np.zeros((n_l, capacity), np.int32),
-            "in_counts": np.zeros((n_l, capacity), np.int32),
-        },
+        "engine_state": engine_state,
         "table": {
             "active": np.zeros(capacity, np.bool_),
             "ended": np.zeros(capacity, np.bool_),

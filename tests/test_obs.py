@@ -31,7 +31,7 @@ import numpy as np
 import pytest
 
 from repro import obs, spidr
-from repro.configs import spidr_gesture
+from repro.configs import lif_firenet, spidr_gesture
 from repro.core.network import init_params
 from repro.obs.metrics import FRACTION_BUCKETS, LATENCY_BUCKETS_S
 
@@ -422,6 +422,27 @@ class TestProfilerSpans:
         for scope in ("spidr.L0.kernel", "spidr.L0.patches",
                       "spidr.L0.counts", "spidr.pool0", "spidr.readout"):
             assert scope in text, scope
+
+    @pytest.mark.parametrize("net", ["firenet", "gesture"])
+    def test_chunk_step_names_the_recurrent_join(self, net):
+        from repro.engine.inference import init_state, run_chunk
+
+        spec = (lif_firenet.reduced(hw=(8, 10), timesteps=2)
+                if net == "firenet"
+                else spidr_gesture.reduced(hw=(16, 16), timesteps=2))
+        compiled = spidr.compile(
+            spec, init_params(jax.random.PRNGKey(0), spec),
+            spidr.DeployTarget(backend="jnp"))
+        engine = compiled.engine
+        step = jax.jit(lambda st, ev: run_chunk(
+            engine, st, ev, collect_counts=True, collect_readouts=True))
+        text = step.lower(init_state(engine, 2), jnp.zeros(
+            (2, 2) + tuple(spec.input_hw) + (2,))).as_text(debug_info=True)
+        if net == "firenet":
+            for scope in ("spidr.L1.recur", "spidr.L4.recur"):
+                assert scope in text, scope
+        else:
+            assert ".recur" not in text
 
 
 # ---------------------------------------------------------------------------

@@ -5,7 +5,8 @@ compiles for a chip that is described, not attached, so these tests catch
 what interpret mode cannot: block shapes the Mosaic lowering refuses,
 operand types the MXU does not take, and reductions it cannot relayout.
 Widths are the paper networks' real ones: gesture conv2 at batch 4 and an
-optical-flow 32->32 layer at batch 1.
+optical-flow 32->32 layer at batch 1; and LIF-FireNet's recurrent layer
+and 1x1 head at one 260x346 frame.
 
 The topology is described inside a module fixture, never at import: only
 one process at a time may load the TPU library, and the test workers must
@@ -80,6 +81,26 @@ def test_fused_int_compiles(one_chip, width, per_channel):
         fn = lambda s, w, v: fused_lif_gemm_int(s, w, v, threshold=3,
                                                 leak_shift=4)
     _assert_kernel_compiles(fn, *args)
+
+
+# LIF-FireNet's widths beyond the paper networks' at one 260x346 frame:
+# a recurrent layer (its input joined to its own spikes, F = 9 * 64) and
+# the 1x1 head (F = 32, K = 2, run at a threshold of v_max + 1).
+FIRENET_WIDTHS = {
+    "firenet_recurrent_b1": ((89960, 576, 32), 28),
+    "firenet_head_b1": ((89960, 32, 2), 64),
+}
+
+
+@pytest.mark.parametrize("width", sorted(FIRENET_WIDTHS))
+def test_fused_int_compiles_at_firenet_widths(one_chip, width):
+    (m, f, k), thr = FIRENET_WIDTHS[width]
+    args = [_sds(one_chip, (m, f), jnp.int8),
+            _sds(one_chip, (f, k), jnp.int8),
+            _sds(one_chip, (m, k), jnp.int32)]
+    _assert_kernel_compiles(
+        lambda s, w, v: fused_lif_gemm_int(s, w, v, threshold=thr,
+                                           leak_shift=1), *args)
 
 
 @pytest.mark.parametrize("width", sorted(WIDTHS))
