@@ -230,12 +230,22 @@ class ChunkOutput:
     readouts: Optional[jax.Array] = None
 
 
-def build_engine(spec: SNNSpec, params, cfg: EngineConfig) -> SNNEngine:
-    """Quantize float params into the integer engine (per-tensor scales)."""
-    with obs_trace.default_tracer().span("engine.build", cat="compile",
-                                         network=spec.name,
-                                         backend=cfg.backend):
-        return _build_engine(spec, params, cfg)
+def build_engine(spec: SNNSpec, params, cfg: EngineConfig,
+                 tune=None) -> SNNEngine:
+    """Quantize float params into the integer engine (per-tensor scales).
+
+    ``tune``, where given, maps the quantized engine to the one served
+    (``spidr.compile``'s autotune), inside the ``engine.build`` span, whose
+    ``pad_w`` is then the number of zero columns the returned engine's
+    chunk walk adds to every plane (:func:`_walk_pad`)."""
+    with obs_trace.default_tracer().span(
+            "engine.build", cat="compile", network=spec.name,
+            backend=cfg.backend) as args:
+        engine = _build_engine(spec, params, cfg)
+        if tune is not None:
+            engine = tune(engine)
+        args["pad_w"] = _walk_pad(engine)
+    return engine
 
 
 def _build_engine(spec: SNNSpec, params, cfg: EngineConfig) -> SNNEngine:
@@ -676,6 +686,73 @@ def _layer_scopes(engine: SNNEngine) -> list:
     return names
 
 
+# ---------------------------------------------------------------------------
+# Tile-aligned planes.  XLA copies a (B, H, W, C) plane reshaped to the
+# kernel's (B*H*W, C) rows, and back, through relayout loops when W is not
+# a multiple of the int8 sublane tile; so the scan walk carries every plane
+# at the input width rounded up to one (346 -> 352 for a DAVIS346 sensor)
+# and cuts it back at the chunk's edge.  The added columns' Vmem and spikes
+# may hold anything: no real column reads them.
+# ---------------------------------------------------------------------------
+_ALIGN_W = 32
+
+
+def _pad_columns(width: int) -> int:
+    """Zero columns that bring ``width`` to a multiple of ``_ALIGN_W``."""
+    return -width % _ALIGN_W
+
+
+def _walk_pad(engine: SNNEngine) -> int:
+    """Zero columns ``run_chunk`` adds to every plane: those that align
+    the input width on the scan walk, none on the ``t_block > 1`` walk."""
+    if _tblk_active(engine):
+        return 0
+    return _pad_columns(engine.spec.input_hw[1])
+
+
+def _to_width(x: jax.Array, width: Optional[int], axis: int) -> jax.Array:
+    """``x`` cut, or zero-padded, to ``width`` along ``axis`` (``x`` itself
+    where it has that width, or ``width`` is None)."""
+    if width is None or x.shape[axis] == width:
+        return x
+    if x.shape[axis] > width:
+        return jax.lax.slice_in_dim(x, 0, width, axis=axis)
+    pad = [(0, 0)] * x.ndim
+    pad[axis] = (0, width - x.shape[axis])
+    return jnp.pad(x, pad)
+
+
+def _planes_at(spec: SNNSpec, hw: tuple) -> list:
+    """``spec.planes()`` of the network walked from an ``hw`` input."""
+    if tuple(hw) == tuple(spec.input_hw):
+        return spec.planes()
+    return dataclasses.replace(spec, input_hw=tuple(hw)).planes()
+
+
+def _plane_width(plane: tuple) -> Optional[int]:
+    """W of an (H, W, C) plane; None for an fc's (N,)."""
+    return plane[1] if len(plane) == 3 else None
+
+
+def _fit_state(spec: SNNSpec, state: EngineState, planes: list):
+    """``state`` with every plane cut or zero-padded to ``planes``' widths:
+    Vmem, previous spikes and a plane-shaped readout."""
+    last = max(i for i, l in enumerate(spec.layers)
+               if l.kind in ("conv", "fc"))
+    fit = [_plane_width(p) for p in planes]
+    return EngineState(
+        vmem=tuple(None if v is None else _to_width(v, w, 2)
+                   for v, w in zip(state.vmem, fit)),
+        readout_acc=(_to_width(state.readout_acc, fit[last], 2)
+                     if spec.readout == "vmem" else state.readout_acc),
+        out_counts=state.out_counts,
+        in_counts=state.in_counts,
+        spikes=tuple(_to_width(z, w, 2) for z, w in zip(
+            state.spikes, [w for l, w in zip(spec.layers, fit)
+                           if l.recurrent])),
+    )
+
+
 def _forward_t(engine: SNNEngine, state, x_t, spikes=()):
     """One timestep through every layer.
 
@@ -685,24 +762,38 @@ def _forward_t(engine: SNNEngine, state, x_t, spikes=()):
     ``(n_weight_layers, B)`` — the batch axis is kept so a streaming
     session can attribute spikes (and therefore chip cost) to the
     individual stream occupying each batch slot.
+
+    ``x_t`` may be wider than the network's input (``run_chunk`` pads it
+    to a tile-aligned width): every plane is then carried at the width of
+    the walk from ``x_t``, and a layer reads only its input's real columns
+    (``spec.planes()``), so the real columns are those of the unpadded
+    walk, bit for bit, and counts count them alone.
     """
     spec = engine.spec
-    shapes = spec.planes()
+    shapes = _planes_at(spec, x_t.shape[1:3])
+    real = [spec.input_hw[1]] + [_plane_width(p) for p in spec.planes()]
     act = x_t  # float {0,1} spike plane (im2col needs float)
     prev = iter(spikes)
     new_state, new_spikes, counts_out, counts_in, out = [], [], [], [], None
     for i, (el, layer, v, scope) in enumerate(zip(
             engine.layers, spec.layers, state, _layer_scopes(engine))):
         if el.kind == "conv":
-            b = act.shape[0]
+            b, wide = act.shape[0], act.shape[2]
             if layer.recurrent:
                 with jax.named_scope(f"{scope}.recur"):
                     act = jnp.concatenate(
                         [act, next(prev).astype(jnp.float32)], axis=-1)
             with jax.named_scope(f"{scope}.counts"):
-                counts_in.append(jnp.sum(act != 0, axis=(1, 2, 3)))
+                counts_in.append(jnp.sum(_to_width(act, real[i], 2) != 0,
+                                         axis=(1, 2, 3)))
             with jax.named_scope(f"{scope}.patches"):
-                cols = im2col(act, el.kh, el.kw, el.stride, el.padding)
+                # The real columns, zero beyond them as in the unpadded
+                # plane, in one pad with im2col's own.
+                p = el.padding
+                x = jnp.pad(_to_width(act, real[i], 2),
+                            ((0, 0), (p, p), (p, p + wide - real[i]),
+                             (0, 0)))
+                cols = im2col(x, el.kh, el.kw, el.stride, 0)
                 rows, f = b * cols.shape[1], cols.shape[2]   # (B*P, F)
                 k = el.w_q.shape[1]
                 s2 = cols.reshape(rows, f).astype(jnp.int8)
@@ -719,13 +810,14 @@ def _forward_t(engine: SNNEngine, state, x_t, spikes=()):
                 s = s.reshape(plane)
             new_state.append(None if layer.stateless else v_next)
             with jax.named_scope(f"{scope}.counts"):
-                counts_out.append(jnp.sum(s, axis=(1, 2, 3)))
+                counts_out.append(jnp.sum(_to_width(s, real[i + 1], 2),
+                                          axis=(1, 2, 3)))
             if layer.recurrent:
                 new_spikes.append(s.astype(jnp.int8))
             act, out = s.astype(jnp.float32), (v_next, s)
         elif el.kind == "fc":
             with jax.named_scope(f"{scope}.patches"):
-                flat = act.reshape(act.shape[0], -1)
+                flat = _to_width(act, real[i], 2).reshape(act.shape[0], -1)
                 s2 = flat.astype(jnp.int8)
             with jax.named_scope(f"{scope}.counts"):
                 counts_in.append(jnp.sum(flat != 0, axis=1))
@@ -736,6 +828,7 @@ def _forward_t(engine: SNNEngine, state, x_t, spikes=()):
                 counts_out.append(jnp.sum(s, axis=1))
             act, out = s.astype(jnp.float32), (v_next, s)
         elif el.kind == "pool":
+            # A real output column reads two real input columns only.
             with jax.named_scope(scope):
                 act = maxpool2d(act)
             new_state.append(None)
@@ -743,7 +836,8 @@ def _forward_t(engine: SNNEngine, state, x_t, spikes=()):
             hw = act.shape[1]
             kk = hw // el.target_hw
             with jax.named_scope(scope):
-                act = maxpool2d(act, window=kk, stride=kk)
+                act = maxpool2d(_to_width(act, real[i], 2),
+                                window=kk, stride=kk)
             new_state.append(None)
     return (new_state, tuple(new_spikes), out, jnp.stack(counts_out),
             jnp.stack(counts_in))
@@ -811,12 +905,23 @@ def run_chunk(
     memory in the total stream length; the optional per-timestep stacks in
     the returned :class:`ChunkOutput` are O(chunk_T) and can be switched
     off for long whole-stream runs (``collect_counts=False``).
+
+    Where the event width is not a multiple of ``_ALIGN_W`` the scan walks
+    planes padded with zero columns up to one (``spidr.align`` pads the
+    events and the state on entry and cuts the state and readouts back on
+    exit), so ``state'`` keeps the shapes of ``init_state``.
     """
     assert events.ndim == 5, "expected (chunk_T, B, H, W, C)"
     spec = engine.spec
     if _tblk_active(engine):
         return _run_chunk_tiled(engine, state, events,
                                 collect_counts, collect_readouts)
+    pad = _walk_pad(engine)
+    if pad:
+        with jax.named_scope("spidr.align"):
+            events = _to_width(events, events.shape[3] + pad, 3)
+            state = _fit_state(spec, state,
+                               _planes_at(spec, events.shape[2:4]))
 
     def step(carry, x_t):
         vmem, spikes, acc, oc, ic = carry
@@ -837,6 +942,12 @@ def run_chunk(
         step, carry0, events)
     new_state = EngineState(vmem=vmem, readout_acc=acc,
                             out_counts=oc, in_counts=ic, spikes=spikes)
+    if pad:
+        with jax.named_scope("spidr.align"):
+            new_state = _fit_state(spec, new_state, spec.planes())
+            acc = new_state.readout_acc
+            if accs is not None and acc.ndim == 4:
+                accs = _to_width(accs, acc.shape[2], 3)
     slot_out = slot_in = None
     sum_out = sum_in = None
     if collect_counts:

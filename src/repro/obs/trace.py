@@ -55,7 +55,7 @@ class _NullSpan:
     __slots__ = ()
 
     def __enter__(self):
-        return self
+        return {}
 
     def __exit__(self, *exc):
         return False
@@ -116,7 +116,7 @@ class Tracer:
         t0 = self._now_us()
         try:
             with _profiler_annotation()(name, **args):
-                yield self
+                yield args
         finally:
             t1 = self._now_us()
             ev = {
@@ -133,7 +133,12 @@ class Tracer:
             self._emit(ev)
 
     def span(self, name: str, cat: str = "spidr", **args):
-        """Context manager recording a complete (``ph: "X"``) event."""
+        """Context manager recording a complete (``ph: "X"``) event.
+
+        It yields the event's ``args``: an argument known only at the
+        block's end is set there (``with tracer.span(...) as args: ...;
+        args["k"] = v``) and recorded with the event, though not with the
+        profiler's annotation, which opens with the block."""
         if not self.enabled:
             return _NULL_SPAN
         return self._span(name, cat, args)

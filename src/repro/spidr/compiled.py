@@ -814,6 +814,12 @@ def compile(network, params=None, target: Optional[DeployTarget] = None,
 def _compile(network, params, target: DeployTarget,
              spec: Optional[SNNSpec]) -> CompiledSNN:
     cfg = _engine_config(target)
+
+    def tuned(engine: SNNEngine) -> SNNEngine:
+        if target.autotune and cfg.backend == "fused":
+            return _autotune_engine(engine, spec, target, cfg)
+        return engine
+
     if isinstance(network, ExportedNetwork):
         if spec is None and isinstance(params, SNNSpec):
             spec, params = params, None
@@ -828,7 +834,7 @@ def _compile(network, params, target: DeployTarget,
                 f"network was exported at {network.weight_bits}-bit — "
                 f"re-export, or deploy with DeployTarget(weight_bits="
                 f"{network.weight_bits})")
-        base = deploy(network, spec, cfg, n_cores=1)
+        base = tuned(deploy(network, spec, cfg, n_cores=1))
         exported = network
     elif isinstance(network, SNNSpec):
         spec = network
@@ -839,7 +845,7 @@ def _compile(network, params, target: DeployTarget,
                 "core.network.init_params or a snn.train fit; a trained "
                 "integer artifact deploys via compile(exported, spec, "
                 "target) instead")
-        base = build_engine(spec, params, cfg)
+        base = build_engine(spec, params, cfg, tune=tuned)
         exported = None
     else:
         raise TypeError(
@@ -848,8 +854,6 @@ def _compile(network, params, target: DeployTarget,
             "core.network.gesture_net/optical_flow_net (or a config's "
             "reduced()), or an exported network with snn.train + "
             "snn.export")
-    if target.autotune and cfg.backend == "fused":
-        base = _autotune_engine(base, spec, target, cfg)
     with obs_trace.default_tracer().span(
             "compiler.schedule", cat="compile", n_cores=target.n_cores):
         engine = _apply_schedule(base, spec, target, cfg)

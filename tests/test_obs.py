@@ -246,6 +246,15 @@ class TestTracer:
         (ev,) = [e for e in tr.to_chrome()["traceEvents"] if e["ph"] == "X"]
         assert ev["args"] == {"layer": 3, "kind": "conv"}
 
+    @pytest.mark.parametrize("enabled", [True, False])
+    def test_span_args_set_at_the_end_are_recorded(self, enabled):
+        tr = obs.Tracer(enabled=enabled)
+        with tr.span("s", layer=3) as args:
+            args["pad_w"] = 6
+        spans = [e for e in tr.to_chrome()["traceEvents"] if e["ph"] == "X"]
+        assert [e["args"] for e in spans] == (
+            [{"layer": 3, "pad_w": 6}] if enabled else [])
+
     def test_disabled_tracer_records_nothing(self):
         tr = obs.Tracer(enabled=False)
         assert not tr

@@ -6,7 +6,9 @@ what interpret mode cannot: block shapes the Mosaic lowering refuses,
 operand types the MXU does not take, and reductions it cannot relayout.
 Widths are the paper networks' real ones: gesture conv2 at batch 4 and an
 optical-flow 32->32 layer at batch 1; and LIF-FireNet's recurrent layer
-and 1x1 head at one 260x346 frame.
+and 1x1 head at one 260x346 frame.  One whole chunk step is compiled too:
+LIF-FireNet's at 16 slots of 260x346 frames, which must hold no relayout
+loop and fit the chip.
 
 The topology is described inside a module fixture, never at import: only
 one process at a time may load the TPU library, and the test workers must
@@ -15,11 +17,16 @@ all collect the same tests.
 The same file checks where the persistent compilation cache is placed.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
 import pytest
 
+from repro import spidr
+from repro.configs import lif_firenet
+from repro.core.network import init_params
+from repro.engine.inference import init_state, run_chunk
 from repro.kernels.autotune import _default_candidates
 from repro.kernels.fused_lif_gemm import (
     fused_lif_gemm_int,
@@ -101,6 +108,31 @@ def test_fused_int_compiles_at_firenet_widths(one_chip, width):
     _assert_kernel_compiles(
         lambda s, w, v: fused_lif_gemm_int(s, w, v, threshold=thr,
                                            leak_shift=1), *args)
+
+
+def test_firenet_chunk_step_has_no_relayout_loops(one_chip):
+    """The served chunk step (16 slots, two timesteps) at 260x346: carried
+    at a tile-aligned width, no (B, H, W, C) <-> (B*H*W, C) reshape is
+    copied through a ``wide.*`` relayout loop (58 of them at 346 wide),
+    and arguments, outputs and temporaries fit in 13.5 GB."""
+    spec = lif_firenet.CONFIG
+    engine = spidr.compile(
+        spec, init_params(jax.random.PRNGKey(0), spec),
+        spidr.DeployTarget(backend="fused", interpret=False)).engine
+    slots = 16
+    state = jax.tree.map(lambda a: _sds(one_chip, a.shape, a.dtype),
+                         jax.eval_shape(lambda: init_state(engine, slots)))
+    events = _sds(one_chip, (2, slots) + spec.input_hw + (2,), jnp.float32)
+    compiled = jax.jit(lambda st, ev: run_chunk(
+        engine, st, ev, collect_counts=True,
+        collect_readouts=True)).lower(state, events).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert not re.search(r"wide\.\S*body", text)
+    mem = compiled.memory_analysis()
+    total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+             + mem.temp_size_in_bytes - mem.alias_size_in_bytes)
+    assert total <= 13.5e9, total
 
 
 @pytest.mark.parametrize("width", sorted(WIDTHS))
