@@ -16,9 +16,8 @@ the reproduced quantity vs the paper's reported value.
   engine_zero_skip       (TPU adaptation): fused multi-timestep engine —
                          zero-skip vs dense ablation at several sparsity
                          levels, exactness vs the pure-jnp reference
-  kernel_blocksparse     (perf gate): the block-sparse Vmem-stationary
-                         hot path — T_blk-tiled fused kernels vs the
-                         per-timestep fused path vs the jnp oracle at
+  kernel_blocksparse     (perf gate): the block-sparse fused kernel —
+                         zero-skip vs dense vs the jnp oracle at
                          several sparsities, recording measured wall-us
                          NEXT TO the analytic roofline bound
                          (``roofline.analysis.PerfModel``) so
@@ -436,14 +435,13 @@ def _clustered_events(rng, timesteps, hw, sparsity, batch=1):
 
 
 def kernel_blocksparse(smoke: bool = False):
-    """Perf-gate ablation: the block-sparse Vmem-stationary hot path.
+    """Perf-gate ablation: the block-sparse fused kernel.
 
-    Runs the reduced gesture network through four schedules of the SAME
-    computation — the T_blk-tiled fused kernel with block skipping
-    (``t_block=T``), the same tiling dense (``skip_empty=False``), the
-    per-timestep fused kernel (``t_block=1``) and the pure-jnp oracle —
-    on clustered DVS-like event streams at several global sparsities,
-    asserting all four bit-exact.  Next to every measured wall time it
+    Runs the reduced gesture network through three schedules of the SAME
+    computation — the per-timestep fused kernel with block skipping, the
+    same kernel dense (``skip_empty=False``) and the pure-jnp oracle — on
+    clustered DVS-like event streams at several global sparsities,
+    asserting all three bit-exact.  Next to the measured wall time it
     records the analytic roofline bound from
     ``roofline.analysis.PerfModel`` (via ``CompiledSNN.roofline``),
     priced with the MEASURED first-layer nonzero-tile fraction
@@ -471,68 +469,53 @@ def kernel_blocksparse(smoke: bool = False):
     spec = spidr_gesture.reduced(hw=hw, timesteps=timesteps)
     params = init_params(jax.random.PRNGKey(0), spec)
     block = (128, 128, 128)
-    tblk_target = spidr.DeployTarget(
+    pert_target = spidr.DeployTarget(
         weight_bits=4, backend="fused", interpret=True, block=block,
-        skip_empty=True, t_block=timesteps)
-    tblk_eng = spidr.compile(spec, params, tblk_target)
+        skip_empty=True)
+    pert_eng = spidr.compile(spec, params, pert_target)
     dense_eng = spidr.compile(spec, params,
-                              dataclasses.replace(tblk_target,
+                              dataclasses.replace(pert_target,
                                                   skip_empty=False))
-    pert_eng = spidr.compile(spec, params,
-                             dataclasses.replace(tblk_target, t_block=1))
     jnp_eng = spidr.compile(spec, params,
-                            dataclasses.replace(tblk_target, backend="jnp",
-                                                t_block=1))
+                            dataclasses.replace(pert_target, backend="jnp"))
     n_weight_layers = len(spec.layer_shapes())
 
     rng = np.random.default_rng(0)
     sparsities = (0.95,) if smoke else (0.60, 0.90, 0.95)
     for s in sparsities:
         ev = jnp.asarray(_clustered_events(rng, timesteps, hw, s))
-        out_tblk = tblk_eng.run(ev)
-        out_dense = dense_eng.run(ev)
         out_pert = pert_eng.run(ev)
+        out_dense = dense_eng.run(ev)
         out_jnp = jnp_eng.run(ev)
         exact = bool(
-            (np.asarray(out_tblk.readout) == np.asarray(out_dense.readout)).all()
-            and (np.asarray(out_tblk.readout) == np.asarray(out_pert.readout)).all()
-            and (np.asarray(out_tblk.readout) == np.asarray(out_jnp.readout)).all()
-            and (np.asarray(out_tblk.spike_counts)
+            (np.asarray(out_pert.readout) == np.asarray(out_dense.readout)).all()
+            and (np.asarray(out_pert.readout) == np.asarray(out_jnp.readout)).all()
+            and (np.asarray(out_pert.spike_counts)
                  == np.asarray(out_jnp.spike_counts)).all()
         )
-        us_tblk = _timeit(lambda: jax.block_until_ready(tblk_eng.run(ev)), n=1)
         us_dense = _timeit(lambda: jax.block_until_ready(dense_eng.run(ev)), n=1)
         us_pert = _timeit(lambda: jax.block_until_ready(pert_eng.run(ev)), n=1)
         # Measured block sparsity at the input layer: the im2col spike
-        # matrix's nonzero-(bm x bk)-tile fraction, exactly what the kernel
-        # prologue computes.  Deeper layers are priced dense (their spike
-        # stacks live on-device; pricing them dense only makes the bound a
-        # firmer floor — the ratio gate tracks relative change either way).
+        # matrix's nonzero-(bm x bk)-tile fraction, the tiles the kernel's
+        # zero-skip passes over.  Deeper layers are priced dense (their
+        # spike stacks live on-device; pricing them dense only makes the
+        # bound a firmer floor — the ratio gate tracks relative change
+        # either way).
         cols = jnp.stack([im2col(ev[t], 3, 3, 1, 1)[0] for t in range(timesteps)])
         frac0 = float(spike_tile_bitmap(cols.astype(jnp.int8), block).mean())
         fracs = [frac0] + [1.0] * (n_weight_layers - 1)
-        bound_tblk = tblk_eng.roofline(batch=1, nonzero_tile_fracs=fracs)
         bound_pert = pert_eng.roofline(batch=1, nonzero_tile_fracs=fracs)
         pct = int(s * 100)
-        _row(f"kernel_s{pct}_tblk", us_tblk,
-             f"exact={exact} bound_us={bound_tblk['bound_us']:.1f} "
-             f"nonzero_tile_frac={frac0:.2f} "
-             f"speedup_vs_dense={us_dense / max(us_tblk, 1):.2f}x "
-             f"speedup_vs_per_t={us_pert / max(us_tblk, 1):.2f}x")
         _row(f"kernel_s{pct}_per_t", us_pert,
-             f"bound_us={bound_pert['bound_us']:.1f}")
-        common = dict(ablation="kernel_blocksparse", sparsity=s,
-                      nonzero_tile_frac=frac0, exact=exact)
-        _record(f"kernel_s{pct}_tblk", t_block=timesteps,
-                wall_us=float(us_tblk), bound_us=float(bound_tblk["bound_us"]),
-                bytes_moved=float(bound_tblk["bytes_moved"]),
-                macs=float(bound_tblk["macs"]),
-                speedup_vs_dense=float(us_dense / max(us_tblk, 1)),
-                speedup_vs_per_t=float(us_pert / max(us_tblk, 1)), **common)
-        _record(f"kernel_s{pct}_per_t", t_block=1,
+             f"exact={exact} bound_us={bound_pert['bound_us']:.1f} "
+             f"nonzero_tile_frac={frac0:.2f} "
+             f"speedup_vs_dense={us_dense / max(us_pert, 1):.2f}x")
+        _record(f"kernel_s{pct}_per_t",
                 wall_us=float(us_pert), bound_us=float(bound_pert["bound_us"]),
                 bytes_moved=float(bound_pert["bytes_moved"]),
-                macs=float(bound_pert["macs"]), **common)
+                macs=float(bound_pert["macs"]),
+                ablation="kernel_blocksparse", sparsity=s,
+                nonzero_tile_frac=frac0, exact=exact)
 
 
 def compiler_multicore(smoke: bool = False):

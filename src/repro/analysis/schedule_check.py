@@ -4,11 +4,11 @@
 construction — this pass re-proves them on the *artifact*, so a schedule
 that was tampered with, deserialized from an old artifact, or produced by
 a future search-based placer (ROADMAP "Compiler v2") is certified before
-the engine bakes it into weights:
+an engine carries it:
 
   * **capacity / coverage** — every slice lands inside the
     :class:`CoreGrid`; each layer's slices are contiguous, non-overlapping
-    and cover exactly ``[0, out_channels)`` (the engine reassembles
+    and cover exactly ``[0, out_channels)`` (the modelled chip reassembles
     outputs by concatenation — a gap or overlap silently corrupts them).
   * **precision legality** — the schedule's ``qspec`` and every plan's
     spec must be a supported ``(B_w, B_vmem)`` pair

@@ -17,12 +17,12 @@ Four stages, one module each:
   ``schedule``   emit the executable :class:`CoreSchedule` pytree
                  (:func:`compile_network`).
 
-The engine runs a schedule via :func:`repro.engine.compile_engine` —
-lockstep ``vmap`` emulation on one device, ``shard_map`` over a ``cores``
-mesh axis when the host has enough devices — bit-exactly with the
-single-core path.  ``repro.engine.cost.estimate_multicore_cost`` prices a
-run per core, including the modeled spike-routing overhead and the load-
-imbalance metric.
+The engine carries a schedule via :func:`repro.engine.compile_engine`
+and runs its one-core walk, whose outputs a schedule's equal (integer
+GEMM and neuron update are column-independent).
+``repro.engine.cost.estimate_multicore_cost`` prices a run per core,
+including the modeled spike-routing overhead and the load-imbalance
+metric.
 
 This package imports only ``repro.core`` (never ``repro.engine``), so the
 engine can depend on it without cycles.

@@ -20,9 +20,9 @@ the right level of parallelism is a *per-layer* decision):
   are the routed traffic.
 
 Output channels are always partitioned into *contiguous* slices covering
-``[0, out_channels)`` in order — the engine reassembles a layer's output
-by concatenating slice results, which keeps multi-core execution bit-exact
-with the single-core path (an integer GEMM + per-channel neuron update is
+``[0, out_channels)`` in order — the modelled chip reassembles a layer's
+output by concatenating slice results, which keeps a multi-core plan's
+outputs those of one core (an integer GEMM + per-channel neuron update is
 column-independent).
 """
 from __future__ import annotations

@@ -9,10 +9,11 @@ spike).
 
 The schedule is registered as a JAX pytree whose leaves are empty — it is
 pure static metadata, safe to close over inside ``jit`` and to carry in
-other pytrees without tracing surprises.  The engine consumes it via
-``repro.engine.compile_engine``, which bakes the channel slices into
-stacked per-core weight tensors and executes them lockstep (``vmap``) or
-on real devices (``shard_map`` over a ``cores`` mesh axis).
+other pytrees without tracing surprises.  The engine carries it via
+``repro.engine.compile_engine`` for pricing
+(``repro.engine.cost.estimate_multicore_cost``); the channel slices are
+the modelled chip's placement, and the engine computes the same spikes
+without splitting them.
 
 Routing model.  A layer's input spikes live on the core(s) that produced
 them (the previous weight layer's slices; pools are core-transparent).

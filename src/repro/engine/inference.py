@@ -12,12 +12,12 @@ modeled one macro drain / one GEMM at a time; the engine closes the loop:
       pool : maxpool on the spike plane (binary in, binary out)
   readout: summed output spikes ("rate") or final-layer Vmem ("vmem")
 
-Beyond a chain (``core.network``'s layer flags; only this scan walk runs
-them): a recurrent conv joins its own previous spikes (carried in
-``EngineState.spikes``) to its input before im2col, so one GEMM of fan-in
-``kh*kw*(c_in + c_out)`` takes both; a stateless head runs the same
-update from a zero Vmem with a threshold it cannot reach, so its output is
-its saturated current and it carries no state.
+Beyond a chain (``core.network``'s layer flags): a recurrent conv joins
+its own previous spikes (carried in ``EngineState.spikes``) to its input
+before im2col, so one GEMM of fan-in ``kh*kw*(c_in + c_out)`` takes both;
+a stateless head runs the same update from a zero Vmem with a threshold
+it cannot reach, so its output is its saturated current and it carries
+no state.
 
 Execution modes:
   * backend="fused" — the Pallas ``fused_lif_gemm_int`` kernel with
@@ -36,15 +36,13 @@ are bit-identical to a single whole-stream call — the chip analogue is Vmem
 staying resident in the CIM macro while events handshake in asynchronously.
 ``run_engine`` itself is just ``init_state`` + one ``run_chunk``.
 
-Multi-core execution: ``compile_engine(engine, schedule)`` bakes a
-``repro.compiler`` :class:`CoreSchedule` into the engine — every weight
-layer's output channels become stacked per-core slices executed over a
-``cores`` axis (``shard_map`` on a real device mesh, lockstep ``vmap``
-emulation on one device) and reassembled by concatenation.  Because the
-integer GEMM + neuron update are column-independent, the multi-core path
-is bit-exact with the single-core path under any chunking, so the chunked
-API below (and the streaming session manager on top of it) work unchanged
-on a compiled plan.
+Multi-core plans: ``compile_engine(engine, schedule)`` attaches a
+``repro.compiler`` :class:`CoreSchedule` to the engine.  The plan is the
+modelled chip's: ``engine.cost.estimate_multicore_cost`` prices its
+per-core cycles, energy and routing from the schedule and the per-layer
+spike counts.  Execution does not split: the integer GEMM and the neuron
+update are column-independent, so an ``n_cores`` plan's spikes, Vmem and
+counts are the one-core walk's, and the engine runs that walk.
 
 Batch handling: the batch dimension is *folded into the GEMM rows*
 (B output positions x P patches share one weight-stationary pass —
@@ -71,24 +69,17 @@ and can be disabled entirely with ``collect_counts=False``.
 from __future__ import annotations
 
 import dataclasses
-import functools
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
-import numpy as np
-from jax.sharding import Mesh, PartitionSpec as P
 
 from ..compiler.schedule import CoreSchedule
 from ..core.layers import im2col, maxpool2d
 from ..core.network import SNNSpec, _init_spikes, _init_state
 from ..core.neuron import NeuronConfig, neuron_step_int
 from ..core.quant import QuantSpec, quantize, saturate
-from ..kernels.fused_lif_gemm import (
-    DEFAULT_BLOCK,
-    fused_lif_gemm_int,
-    fused_lif_gemm_int_tblk,
-)
+from ..kernels.fused_lif_gemm import DEFAULT_BLOCK, fused_lif_gemm_int
 from ..obs import trace as obs_trace
 
 __all__ = [
@@ -116,16 +107,9 @@ class EngineConfig:
     interpret: bool = False       # Pallas interpret mode (CPU)
     skip_empty: bool = True       # tile-level zero-skipping
     block: tuple = DEFAULT_BLOCK
-    # Vmem-stationary timestep tiling: >1 routes fused-backend chunks
-    # through the layer-outer T_blk path (``fused_lif_gemm_int_tblk``) —
-    # each weight block is touched once per ``t_block`` timesteps instead
-    # of once per timestep.  Bit-exact with the scan path for any value.
-    t_block: int = 1
 
     def __post_init__(self):
         assert self.backend in ("fused", "jnp"), self.backend
-        assert isinstance(self.t_block, int) and self.t_block >= 1, \
-            self.t_block
 
 
 @dataclasses.dataclass(frozen=True)
@@ -145,17 +129,6 @@ class EngineLayer:
     stride: int = 1
     padding: int = 0
     target_hw: int = 0                    # adaptive pool target
-    # Multi-core placement (set by ``compile_engine`` from a CoreSchedule):
-    # stacked per-core channel slices of ``w_q``, zero-padded to the widest
-    # slice, plus each core's (lo, hi) channel range ((0, 0) = idle core).
-    w_cores: Optional[jax.Array] = None   # (n_cores, F, Kc) int8
-    core_slices: tuple = ()               # per-core (lo, hi), len n_cores
-    # Per-core slices of a per-channel ``thr_int`` (padding gets v_max+1 so
-    # padded channels never spike); None when ``thr_int`` is a scalar.
-    thr_cores: Optional[jax.Array] = None  # (n_cores, Kc) int32
-    # Autotuned kernel config override: (block_m, block_n, block_k, t_blk).
-    # None falls back to the engine-wide ``cfg.block`` / ``cfg.t_block``.
-    kcfg: Optional[tuple] = None
 
 
 @dataclasses.dataclass(frozen=True)
@@ -163,11 +136,9 @@ class SNNEngine:
     spec: SNNSpec
     cfg: EngineConfig
     layers: tuple  # of EngineLayer
-    # Multi-core plan (None = single-core).  ``compile_engine`` sets both;
-    # ``device_parallel`` selects shard_map over a "cores" mesh axis (real
-    # devices) vs lockstep vmap emulation (single device).
+    # The modelled chip's multi-core plan (None = single-core), attached
+    # by ``compile_engine`` for pricing; it does not change execution.
     schedule: Optional[CoreSchedule] = None
-    device_parallel: bool = False
 
 
 @dataclasses.dataclass
@@ -230,21 +201,16 @@ class ChunkOutput:
     readouts: Optional[jax.Array] = None
 
 
-def build_engine(spec: SNNSpec, params, cfg: EngineConfig,
-                 tune=None) -> SNNEngine:
+def build_engine(spec: SNNSpec, params, cfg: EngineConfig) -> SNNEngine:
     """Quantize float params into the integer engine (per-tensor scales).
 
-    ``tune``, where given, maps the quantized engine to the one served
-    (``spidr.compile``'s autotune), inside the ``engine.build`` span, whose
-    ``pad_w`` is then the number of zero columns the returned engine's
-    chunk walk adds to every plane (:func:`_walk_pad`)."""
+    The ``engine.build`` span's ``pad_w`` is the number of zero columns
+    the engine's chunk walk adds to every plane (:func:`_walk_pad`)."""
     with obs_trace.default_tracer().span(
             "engine.build", cat="compile", network=spec.name,
             backend=cfg.backend) as args:
         engine = _build_engine(spec, params, cfg)
-        if tune is not None:
-            engine = tune(engine)
-        args["pad_w"] = _walk_pad(engine)
+        args["pad_w"] = _walk_pad(spec)
     return engine
 
 
@@ -287,21 +253,17 @@ def _build_engine(spec: SNNSpec, params, cfg: EngineConfig) -> SNNEngine:
 # One fused layer-timestep.
 # ---------------------------------------------------------------------------
 def _fused_update(el: EngineLayer, s2: jax.Array, v2: jax.Array,
-                  cfg: EngineConfig, w_q: Optional[jax.Array] = None,
-                  thr=None):
+                  cfg: EngineConfig, thr=None):
     """(rows, F) spikes x (F, K) weights + (rows, K) Vmem -> (v', s).
 
-    ``w_q``/``thr`` override the layer's weights and integer threshold —
-    the multi-core path maps this function over per-core channel slices of
-    the weight matrix (and, for per-channel-quantized layers, of the
-    threshold vector).
+    ``thr`` overrides the layer's integer threshold (a stateless head runs
+    at one it cannot reach).
     """
     n = el.neuron
-    w = el.w_q if w_q is None else w_q
     thr = el.thr_int if thr is None else thr
     if cfg.backend == "fused":
         return fused_lif_gemm_int(
-            s2, w, v2,
+            s2, el.w_q, v2,
             threshold=thr,
             leak_shift=n.leak_shift if n.model == "lif" else 0,
             soft_reset=(n.reset == "soft"),
@@ -311,7 +273,7 @@ def _fused_update(el: EngineLayer, s2: jax.Array, v2: jax.Array,
             skip_empty=cfg.skip_empty,
         )
     acc = jnp.dot(
-        s2.astype(jnp.int32), w.astype(jnp.int32),
+        s2.astype(jnp.int32), el.w_q.astype(jnp.int32),
         preferred_element_type=jnp.int32,
     )
     partial = saturate(acc, cfg.qspec)
@@ -322,352 +284,29 @@ def _fused_update(el: EngineLayer, s2: jax.Array, v2: jax.Array,
     return neuron_step_int(v2, partial, n, cfg.qspec, thr)
 
 
-# ---------------------------------------------------------------------------
-# Multi-core execution (compiled CoreSchedule): each weight layer's output
-# channels live as per-core slices.  Every core scans the full input spike
-# plane into its own slice's weights (the spike-routing the cost model
-# charges), so per-channel results are identical to the single-core GEMM —
-# integer GEMM + neuron update are column-independent, which is what makes
-# the reassembled output bit-exact.
-# ---------------------------------------------------------------------------
-@functools.lru_cache(maxsize=None)
-def _cores_mesh(n_cores: int) -> Mesh:
-    """The ``cores`` device mesh axis (first ``n_cores`` local devices)."""
-    return Mesh(np.array(jax.devices()[:n_cores]), ("cores",))
+def compile_engine(engine: SNNEngine, schedule: CoreSchedule) -> SNNEngine:
+    """Attach a compiler :class:`CoreSchedule` to an executable engine.
 
-
-def _multicore_apply(el: EngineLayer, s2: jax.Array, v2: jax.Array,
-                     cfg: EngineConfig, device_parallel: bool, core_update):
-    """Run one layer's per-core channel slices and reassemble the output.
-
-    ``el.w_cores`` is ``(C, F, Kc)``; core ``c`` computes channels
-    ``[lo_c, hi_c)`` against the *same* spike matrix (replicated — the
-    engine analogue of routing the input spikes to every consumer core).
-    Idle cores carry zero-width slices padded with zero weights; their
-    results are discarded at reassembly.
-
-    ``core_update(sp, blocks)`` runs one core's slice, ``blocks`` =
-    ``(w, [thr,] v)``, and returns a ``(v-like, s-like)`` pair whose
-    *last* axis is the channel axis — the single-timestep update returns
-    ``(rows, Kc)`` pairs, the T_blk update ``(T, rows, Kc)`` stacks; the
-    slicing/reassembly below is rank-agnostic.
-    """
-    n_cores, _, kc = el.w_cores.shape
-
-    def pad_slice(lo, hi):
-        vc = v2[:, lo:hi]
-        if hi - lo < kc:
-            vc = jnp.pad(vc, ((0, 0), (0, kc - (hi - lo))))
-        return vc
-
-    # Per-core operands mapped over the ``cores`` axis: the weight slices,
-    # plus (for per-channel-quantized layers) the threshold slices.  A
-    # scalar threshold stays baked into the kernel via ``el.thr_int``.
-    per_core_ops = [el.w_cores]
-    if el.thr_cores is not None:
-        per_core_ops.append(el.thr_cores)
-
-    if device_parallel and n_cores > 1:
-        # Full (n_cores, ...) stack: shard_map needs one uniform block per
-        # mesh device, so idle cores ride along with zero weights (they are
-        # idle silicon either way).
-        v_cores = jnp.stack([pad_slice(lo, hi) for lo, hi in el.core_slices])
-        fn = jax.shard_map(
-            lambda sp, *blocks: jax.vmap(
-                lambda *bs: core_update(sp, bs))(*blocks),
-            mesh=_cores_mesh(n_cores),
-            in_specs=(P(),) + (P("cores"),) * (len(per_core_ops) + 1),
-            out_specs=(P("cores"), P("cores")),
-            check_vma=False,
-        )
-        v_next, s = fn(s2, *per_core_ops, v_cores)
-        row = {c: c for c in range(n_cores)}
-    else:
-        # Lockstep vmapped emulation on one device: only the cores that
-        # actually hold a slice compute — a whole layer placed on one core
-        # must not cost n_cores zero-weight GEMMs.
-        active = tuple(c for c in range(n_cores)
-                       if el.core_slices[c][1] > el.core_slices[c][0])
-        idx = np.asarray(active)
-        v_cores = jnp.stack([pad_slice(*el.core_slices[c]) for c in active])
-        v_next, s = jax.vmap(lambda *bs: core_update(s2, bs))(
-            *[op[idx] for op in per_core_ops], v_cores)
-        row = {c: i for i, c in enumerate(active)}
-
-    # Reassemble output channels in slice order (slices are contiguous and
-    # cover [0, K), so concatenation restores the single-core layout).
-    # ``[..., :width]`` / ``axis=-1`` keep this correct for both the 2-D
-    # single-timestep outputs and the 3-D T_blk trajectory stacks.
-    order = sorted(
-        (c for c in row if el.core_slices[c][1] > el.core_slices[c][0]),
-        key=lambda c: el.core_slices[c][0],
-    )
-    v_out = jnp.concatenate(
-        [v_next[row[c]][..., : el.core_slices[c][1] - el.core_slices[c][0]]
-         for c in order], axis=-1)
-    s_out = jnp.concatenate(
-        [s[row[c]][..., : el.core_slices[c][1] - el.core_slices[c][0]]
-         for c in order], axis=-1)
-    return v_out, s_out
-
-
-def _multicore_update(el: EngineLayer, s2: jax.Array, v2: jax.Array,
-                      cfg: EngineConfig, device_parallel: bool):
-    """Single-timestep multi-core layer update (the original path)."""
-    def core_update(sp, blocks):
-        w, *thr, v = blocks
-        return _fused_update(el, sp, v, cfg, w_q=w,
-                             thr=thr[0] if thr else None)
-
-    return _multicore_apply(el, s2, v2, cfg, device_parallel, core_update)
-
-
-def _layer_update(engine: SNNEngine, el: EngineLayer, s2: jax.Array,
-                  v2: jax.Array):
-    if el.w_cores is not None:
-        return _multicore_update(el, s2, v2, engine.cfg,
-                                 engine.device_parallel)
-    return _fused_update(el, s2, v2, engine.cfg)
-
-
-# ---------------------------------------------------------------------------
-# Vmem-stationary T_blk tiling: the layer-outer chunk path.  Instead of
-# scanning timesteps with a full layer sweep per step, each weight layer
-# consumes the whole chunk as (T, rows, F) spike stacks in T_blk-sized
-# slabs — one ``fused_lif_gemm_int_tblk`` call per slab touches every
-# weight block once for the slab's timesteps (the chip's Vmem-stationary
-# mode 2 reuse, Sec II-E).  Bit-exact with the scan path because integer
-# accumulation is exact and the per-slab neuron program is sequential in t.
-# ---------------------------------------------------------------------------
-def _layer_kcfg(el: EngineLayer, cfg: EngineConfig):
-    """(gemm block, t_blk) for one layer: autotuned override or config."""
-    if el.kcfg is not None:
-        bm, bn, bk, tb = el.kcfg
-        return (bm, bn, bk), tb
-    return cfg.block, cfg.t_block
-
-
-def _tblk_update(el: EngineLayer, s_slab: jax.Array, v2: jax.Array,
-                 cfg: EngineConfig, block: tuple,
-                 w_q: Optional[jax.Array] = None, thr=None):
-    """One T_blk slab: (T, rows, F) spikes -> (T, rows, K) v-traj + spikes."""
-    n = el.neuron
-    w = el.w_q if w_q is None else w_q
-    thr = el.thr_int if thr is None else thr
-    return fused_lif_gemm_int_tblk(
-        s_slab, w, v2,
-        threshold=thr,
-        leak_shift=n.leak_shift if n.model == "lif" else 0,
-        soft_reset=(n.reset == "soft"),
-        vmem_bits=cfg.qspec.vmem_bits,
-        block=block,
-        interpret=cfg.interpret,
-        skip_empty=cfg.skip_empty,
-    )
-
-
-def _layer_update_tblk(engine: SNNEngine, el: EngineLayer,
-                       s_stack: jax.Array, v2: jax.Array):
-    """Walk a (T, rows, F) spike stack through one layer in T_blk slabs.
-
-    ``chunk_T`` need not divide ``t_blk``: the remainder slab is simply a
-    second (static-shape) kernel specialization.  The Vmem carry threads
-    through the slabs, so the result is bit-exact under any slab geometry.
-    """
-    cfg = engine.cfg
-    block, tb = _layer_kcfg(el, cfg)
-    t = s_stack.shape[0]
-
-    def slab_update(slab, v_in):
-        if el.w_cores is None:
-            return _tblk_update(el, slab, v_in, cfg, block)
-
-        def core_update(sp, blocks):
-            w, *thr, v = blocks
-            return _tblk_update(el, sp, v, cfg, block, w_q=w,
-                                thr=thr[0] if thr else None)
-
-        return _multicore_apply(el, slab, v_in, cfg,
-                                engine.device_parallel, core_update)
-
-    v_parts, s_parts = [], []
-    for t0 in range(0, t, tb):
-        v_traj, s = slab_update(s_stack[t0:t0 + tb], v2)
-        v_parts.append(v_traj)
-        s_parts.append(s)
-        v2 = v_traj[-1]
-    if len(v_parts) == 1:
-        return v_parts[0], s_parts[0]
-    return jnp.concatenate(v_parts), jnp.concatenate(s_parts)
-
-
-def _tblk_active(engine: SNNEngine) -> bool:
-    """Route chunks through the layer-outer tiled path?"""
-    if engine.cfg.backend != "fused":
-        return False
-    if engine.cfg.t_block > 1:
-        return True
-    return any(el.kcfg is not None and el.kcfg[3] > 1
-               for el in engine.layers if el.kind in ("conv", "fc"))
-
-
-def _pool_stack(act: jax.Array, window: int, stride: int) -> jax.Array:
-    """maxpool2d over a (T, B, H, W, C) stack via T*B folding."""
-    t, b = act.shape[:2]
-    out = maxpool2d(act.reshape((t * b,) + act.shape[2:]),
-                    window=window, stride=stride)
-    return out.reshape((t, b) + out.shape[1:])
-
-
-def _run_chunk_tiled(engine: SNNEngine, state: EngineState,
-                     events: jax.Array, collect_counts: bool,
-                     collect_readouts: bool):
-    """Layer-outer twin of ``run_chunk``'s scan: same state, same outputs.
-
-    Memory note: this path materializes (chunk_T, ...) activation stacks
-    per layer — O(chunk_T), like ``collect_counts`` — so streams should
-    keep ``chunk_T`` at a small multiple of ``t_block`` (the scan path
-    remains the right tool for huge single-chunk runs).
-    """
-    spec = engine.spec
-    spec.require_chain("the layer-outer t_block walk (t_block > 1)")
-    t, b = events.shape[:2]
-    act = events.astype(jnp.float32)
-    new_vmem, counts_out, counts_in = [], [], []
-    last = None  # (v_traj, s_stack) of the last weight layer
-    for el, v, scope in zip(engine.layers, state.vmem, _layer_scopes(engine)):
-        if el.kind == "conv":
-            with jax.named_scope(f"{scope}.counts"):
-                counts_in.append(jnp.sum(act != 0, axis=(2, 3, 4)))
-            with jax.named_scope(f"{scope}.patches"):
-                flat = act.reshape((t * b,) + act.shape[2:])
-                cols = im2col(flat, el.kh, el.kw, el.stride, el.padding)
-                p, f = cols.shape[1], cols.shape[2]
-                k = el.w_q.shape[1]
-                s_stack = cols.reshape(t, b * p, f).astype(jnp.int8)
-            with jax.named_scope(f"{scope}.kernel"):
-                v_traj, s = _layer_update_tblk(engine, el, s_stack,
-                                               v.reshape(b * p, k))
-                v_traj = v_traj.reshape((t,) + v.shape)
-                s = s.reshape((t,) + v.shape)
-            new_vmem.append(v_traj[-1])
-            with jax.named_scope(f"{scope}.counts"):
-                counts_out.append(jnp.sum(s, axis=(2, 3, 4)))
-            act, last = s.astype(jnp.float32), (v_traj, s)
-        elif el.kind == "fc":
-            with jax.named_scope(f"{scope}.patches"):
-                flat = act.reshape(t, b, -1)
-                s_stack = flat.astype(jnp.int8)
-            with jax.named_scope(f"{scope}.counts"):
-                counts_in.append(jnp.sum(flat != 0, axis=2))
-            with jax.named_scope(f"{scope}.kernel"):
-                v_traj, s = _layer_update_tblk(engine, el, s_stack, v)
-            new_vmem.append(v_traj[-1])
-            with jax.named_scope(f"{scope}.counts"):
-                counts_out.append(jnp.sum(s, axis=2))
-            act, last = s.astype(jnp.float32), (v_traj, s)
-        elif el.kind == "pool":
-            with jax.named_scope(scope):
-                act = _pool_stack(act, 2, 2)
-            new_vmem.append(None)
-        elif el.kind == "adaptive_pool":
-            kk = act.shape[2] // el.target_hw
-            with jax.named_scope(scope):
-                act = _pool_stack(act, kk, kk)
-            new_vmem.append(None)
-    v_traj, s_last = last
-    with jax.named_scope("spidr.readout"):
-        if spec.readout == "rate":
-            accs = state.readout_acc[None] + jnp.cumsum(s_last, axis=0)
-        else:
-            accs = v_traj
-    slot_out = jnp.stack(counts_out, axis=1)   # (chunk_T, L, B)
-    slot_in = jnp.stack(counts_in, axis=1)
-    new_state = EngineState(
-        vmem=tuple(new_vmem),
-        readout_acc=accs[-1],
-        out_counts=state.out_counts + jnp.sum(slot_out, axis=0),
-        in_counts=state.in_counts + jnp.sum(slot_in, axis=0),
-    )
-    return new_state, ChunkOutput(
-        readout=accs[-1],
-        spike_counts=jnp.sum(slot_out, axis=2) if collect_counts else None,
-        input_counts=jnp.sum(slot_in, axis=2) if collect_counts else None,
-        slot_spike_counts=slot_out if collect_counts else None,
-        slot_input_counts=slot_in if collect_counts else None,
-        readouts=accs if collect_readouts else None,
-    )
-
-
-def compile_engine(engine: SNNEngine, schedule: CoreSchedule,
-                   device_parallel: Optional[bool] = None) -> SNNEngine:
-    """Bake a compiler :class:`CoreSchedule` into an executable engine.
-
-    Splits every weight layer's quantized weights into the schedule's
-    per-core channel slices (stacked, zero-padded to the widest slice) and
-    returns an engine whose ``run_chunk``/``run_engine`` execute the
-    multi-core plan — bit-exactly with the single-core engine, under any
-    chunking, so the streaming session manager works unchanged.
-
-    ``device_parallel=None`` auto-selects: ``shard_map`` over a ``cores``
-    mesh axis when the host has at least ``n_cores`` devices, lockstep
-    ``vmap`` emulation otherwise.
+    The schedule is the modelled chip's plan: per-core cycles, energy and
+    routing come from ``estimate_multicore_cost`` over it.  The engine's
+    walk is unchanged — integer GEMM and neuron update are column-
+    independent, so the plan's outputs are the one-core outputs — and so
+    is everything ``run_chunk`` and the session manager compute.
     """
     with obs_trace.default_tracer().span("engine.compile_schedule",
                                          cat="compile",
                                          network=engine.spec.name,
                                          n_cores=schedule.n_cores):
-        return _compile_engine(engine, schedule, device_parallel)
-
-
-def _compile_engine(engine: SNNEngine, schedule: CoreSchedule,
-                    device_parallel: Optional[bool] = None) -> SNNEngine:
-    assert engine.schedule is None, "engine already carries a schedule"
-    engine.spec.require_chain("the multi-core path (n_cores > 1)")
-    for ls in schedule.layers:
-        if ls.plan.spec != engine.cfg.qspec:
-            raise ValueError(
-                f"schedule selected {ls.plan.spec} for layer {ls.node} but "
-                f"the engine executes {engine.cfg.qspec}; precision-"
-                "exploring schedules (allowed_specs) are for cost analysis, "
-                "not execution")
-    n_cores = schedule.n_cores
-    by_node = {ls.node: ls for ls in schedule.layers}
-    new_layers = []
-    for idx, el in enumerate(engine.layers):
-        if el.kind not in ("conv", "fc"):
-            new_layers.append(el)
-            continue
-        ls = by_node[idx]
-        k = el.w_q.shape[1]
-        assert k == ls.out_channels, (k, ls.out_channels)
-        kc = max(s.width for s in ls.slices)
-        w_cores = np.zeros((n_cores, el.w_q.shape[0], kc), np.int8)
-        core_slices = [(0, 0)] * n_cores
-        w_np = np.asarray(el.w_q)
-        # Per-channel-quantized layers carry their threshold vector along
-        # the same channel slices; padding gets v_max+1 (never fires).
-        per_channel = np.ndim(el.thr_int) > 0
-        thr_cores = np.full((n_cores, kc), engine.cfg.qspec.v_max + 1,
-                            np.int32) if per_channel else None
-        for s in ls.slices:
-            w_cores[s.core, :, : s.width] = w_np[:, s.lo:s.hi]
-            core_slices[s.core] = (s.lo, s.hi)
-            if per_channel:
-                thr_cores[s.core, : s.width] = np.asarray(
-                    el.thr_int)[s.lo:s.hi]
-        new_layers.append(dataclasses.replace(
-            el, w_cores=jnp.asarray(w_cores), core_slices=tuple(core_slices),
-            thr_cores=None if thr_cores is None else jnp.asarray(thr_cores)))
-    if device_parallel is None:
-        device_parallel = 1 < n_cores <= len(jax.devices())
-    if device_parallel:
-        assert n_cores <= len(jax.devices()), (
-            f"device_parallel needs {n_cores} devices, "
-            f"host has {len(jax.devices())}")
-    return dataclasses.replace(engine, layers=tuple(new_layers),
-                               schedule=schedule,
-                               device_parallel=bool(device_parallel))
+        assert engine.schedule is None, "engine already carries a schedule"
+        engine.spec.require_chain("the multi-core path (n_cores > 1)")
+        for ls in schedule.layers:
+            if ls.plan.spec != engine.cfg.qspec:
+                raise ValueError(
+                    f"schedule selected {ls.plan.spec} for layer {ls.node} "
+                    f"but the engine executes {engine.cfg.qspec}; precision-"
+                    "exploring schedules (allowed_specs) are for cost "
+                    "analysis, not execution")
+        return dataclasses.replace(engine, schedule=schedule)
 
 
 def _layer_scopes(engine: SNNEngine) -> list:
@@ -697,17 +336,10 @@ def _layer_scopes(engine: SNNEngine) -> list:
 _ALIGN_W = 32
 
 
-def _pad_columns(width: int) -> int:
-    """Zero columns that bring ``width`` to a multiple of ``_ALIGN_W``."""
-    return -width % _ALIGN_W
-
-
-def _walk_pad(engine: SNNEngine) -> int:
-    """Zero columns ``run_chunk`` adds to every plane: those that align
-    the input width on the scan walk, none on the ``t_block > 1`` walk."""
-    if _tblk_active(engine):
-        return 0
-    return _pad_columns(engine.spec.input_hw[1])
+def _walk_pad(spec: SNNSpec) -> int:
+    """Zero columns ``run_chunk`` adds to every plane: those that bring
+    the input width to a multiple of ``_ALIGN_W``."""
+    return -spec.input_hw[1] % _ALIGN_W
 
 
 def _to_width(x: jax.Array, width: Optional[int], axis: int) -> jax.Array:
@@ -804,8 +436,8 @@ def _forward_t(engine: SNNEngine, state, x_t, spikes=()):
                         el, s2, jnp.zeros((rows, k), jnp.int32), engine.cfg,
                         thr=engine.cfg.qspec.v_max + 1)
                 else:
-                    v_next, s = _layer_update(engine, el, s2,
-                                              v.reshape(rows, k))
+                    v_next, s = _fused_update(el, s2, v.reshape(rows, k),
+                                              engine.cfg)
                 v_next = v_next.reshape(plane)
                 s = s.reshape(plane)
             new_state.append(None if layer.stateless else v_next)
@@ -822,7 +454,7 @@ def _forward_t(engine: SNNEngine, state, x_t, spikes=()):
             with jax.named_scope(f"{scope}.counts"):
                 counts_in.append(jnp.sum(flat != 0, axis=1))
             with jax.named_scope(f"{scope}.kernel"):
-                v_next, s = _layer_update(engine, el, s2, v)
+                v_next, s = _fused_update(el, s2, v, engine.cfg)
             new_state.append(v_next)
             with jax.named_scope(f"{scope}.counts"):
                 counts_out.append(jnp.sum(s, axis=1))
@@ -913,10 +545,7 @@ def run_chunk(
     """
     assert events.ndim == 5, "expected (chunk_T, B, H, W, C)"
     spec = engine.spec
-    if _tblk_active(engine):
-        return _run_chunk_tiled(engine, state, events,
-                                collect_counts, collect_readouts)
-    pad = _walk_pad(engine)
+    pad = _walk_pad(spec)
     if pad:
         with jax.named_scope("spidr.align"):
             events = _to_width(events, events.shape[3] + pad, 3)

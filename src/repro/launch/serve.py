@@ -228,8 +228,8 @@ def serve_snn(args):
 
     spec = (spidr_gesture.reduced() if args.snn == "gesture"
             else spidr_optflow.reduced())
-    # Telemetry opt-in must precede spidr.compile so the autotune sweep and
-    # compile spans land in the same registry/trace as the serving loop.
+    # Telemetry opt-in must precede spidr.compile so the compile spans land
+    # in the same registry/trace as the serving loop.
     metrics_out = getattr(args, "metrics_out", None)
     metrics_every = getattr(args, "metrics_every", 0)
     trace_out = getattr(args, "trace_out", None)
@@ -251,10 +251,8 @@ def serve_snn(args):
     compiled = spidr.compile(spec, params, target)
 
     if compiled.schedule is not None:
-        log.info("compiled %s onto %d cores (%d channel-split layers, "
-                 "device_parallel=%s)\n%s", spec.name, args.n_cores,
-                 compiled.schedule.n_split_layers,
-                 compiled.engine.device_parallel,
+        log.info("compiled %s onto %d cores (%d channel-split layers)\n%s",
+                 spec.name, args.n_cores, compiled.schedule.n_split_layers,
                  compiled.schedule.describe())
 
     make = make_gesture_batch if args.snn == "gesture" else make_flow_batch
@@ -421,9 +419,8 @@ def main():
                     help="--streaming: snapshot every N ticks (0 = never)")
     ap.add_argument("--n-cores", type=int, default=1, dest="n_cores",
                     help="SNN path: compile the network across a grid of N "
-                         "SpiDR cores (repro.compiler) — bit-exact outputs, "
-                         "per-core cost attribution; uses a shard_map cores "
-                         "mesh when the host has N devices")
+                         "SpiDR cores (repro.compiler) — the one-core "
+                         "outputs, per-core cost attribution")
     ap.add_argument("--metrics-out", default=None, dest="metrics_out",
                     help="enable telemetry and write the final metrics dump "
                          "here (.json -> JSON, else Prometheus text)")
@@ -433,7 +430,7 @@ def main():
                          "ticks (0 = only at the end)")
     ap.add_argument("--trace-out", default=None, dest="trace_out",
                     help="enable span tracing and export a Chrome-trace/"
-                         "Perfetto JSON (compile + autotune + serving spans; "
+                         "Perfetto JSON (compile + serving spans; "
                          "multi-core runs add per-stream pipeline timelines)")
     ap.add_argument("--log-json", action="store_true", dest="log_json",
                     help="emit one JSON object per log record instead of "

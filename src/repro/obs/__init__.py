@@ -6,8 +6,8 @@ Three pillars (see docs/observability.md):
   Prometheus-text and JSON export; process-wide default registry that is
   **disabled by default** and costs one truthiness check per site when off.
 * :mod:`repro.obs.trace` — context-manager span tracer emitting
-  Chrome-trace/Perfetto JSON (compile -> autotune -> per-chunk run_chunk
-  -> snapshot/restore).
+  Chrome-trace/Perfetto JSON (compile -> per-chunk run_chunk ->
+  snapshot/restore).
 * :mod:`repro.obs.timeline` — renders the simulated per-core async
   pipeline clocks of ``estimate_multicore_cost`` (busy / AER-routing /
   idle intervals) in the same Chrome-trace format.

@@ -290,7 +290,7 @@ def analyze_compiled(compiled, cfg, shape, mesh_devices: int, model_axis: int,
 
 # ---------------------------------------------------------------------------
 # SNN kernel performance model: analytic wall-time bounds for the fused
-# Vmem-stationary T_blk kernel (kernels.fused_lif_gemm_int_tblk).
+# per-timestep kernel (kernels.fused_lif_gemm_int).
 #
 # A thin, explicit wrapper in the style of DaCe's RooflineModel: peaks in,
 # (bytes-moved, MACs-at-sparsity) per layer, bound = max(compute, memory).
@@ -316,9 +316,8 @@ class LayerBound:
     fan_in: int              # GEMM K
     channels: int            # GEMM N
     timesteps: int
-    t_block: int
     macs: float              # MACs actually issued (after tile skipping)
-    bytes_moved: float       # HBM bytes under the T_blk tiling
+    bytes_moved: float       # HBM bytes under the block tiling
     compute_s: float
     memory_s: float
 
@@ -353,40 +352,36 @@ class PerfModel:
         channels: int,
         *,
         timesteps: int,
-        t_block: int = 1,
         nonzero_tile_frac: float = 1.0,
         block: tuple = (128, 128, 128),
     ) -> LayerBound:
-        """Bound one layer's chunk under the T_blk tiling.
+        """Bound one layer's chunk, one kernel call per timestep.
 
         ``nonzero_tile_frac`` is the fraction of (bm x bk) spike tiles
         that carry at least one spike (measure it with
         ``kernels.spike_tile_bitmap``); it scales the MAC term — the
         block-sparsity lever — while the byte terms keep the dense spike
-        stream (the bitmap is read either way; weight traffic is decided
-        by tiling, not sparsity).
+        stream (every spike tile is read either way; weight traffic is
+        decided by tiling, not sparsity).
 
-        Byte model of ``fused_lif_gemm_int_tblk`` per chunk:
+        Byte model of ``fused_lif_gemm_int`` over ``T`` timesteps:
           * weights: the (K_p x N_p) int8 matrix streams once per m-tile
-            per kernel call — ``gm * K_p * N_p * ceil(T / T_blk)``; this
-            is the term the Vmem-stationary tiling divides by T_blk;
-          * spikes: each (T_blk, bm, bk) int8 stack is read once per
-            n-tile — ``T * R_p * K_p * gn``;
+            per call — ``gm * K_p * N_p * T``;
+          * spikes: each (bm, bk) int8 tile is read once per n-tile —
+            ``T * R_p * K_p * gn``;
           * Vmem carry: the (bm, bn) int32 tile reads once per (i, j)
             per call;
-          * outputs: the (T, M, N) int32 trajectory + spike stacks write
-            once each.
+          * outputs: the (M, N) int32 Vmem and spikes write once each a
+            call.
         """
         bm, bn, bk = block
-        t_block = max(1, min(t_block, timesteps))
         r_p, k_p, n_p = _ceil_to(rows, bm), _ceil_to(fan_in, bk), \
             _ceil_to(channels, bn)
         gm, gn = r_p // bm, n_p // bn
-        n_calls = -(-timesteps // t_block)
 
-        w_bytes = float(gm * k_p * n_p) * n_calls
+        w_bytes = float(gm * k_p * n_p) * timesteps
         s_bytes = float(timesteps * r_p * k_p) * gn
-        v_bytes = 4.0 * r_p * n_p * n_calls
+        v_bytes = 4.0 * r_p * n_p * timesteps
         out_bytes = 2.0 * 4.0 * timesteps * r_p * n_p
         bytes_moved = w_bytes + s_bytes + v_bytes + out_bytes
 
@@ -396,8 +391,7 @@ class PerfModel:
         memory_s = bytes_moved / self.peaks["hbm_gbps"]
         return LayerBound(
             rows=rows, fan_in=fan_in, channels=channels,
-            timesteps=timesteps, t_block=t_block,
-            macs=macs, bytes_moved=bytes_moved,
+            timesteps=timesteps, macs=macs, bytes_moved=bytes_moved,
             compute_s=compute_s, memory_s=memory_s,
         )
 
@@ -407,36 +401,26 @@ class PerfModel:
         *,
         batch: int = 1,
         timesteps: Optional[int] = None,
-        t_block: int = 1,
         block: tuple = (128, 128, 128),
         nonzero_tile_fracs=None,
-        layer_kcfgs=None,
     ) -> dict:
         """Aggregate per-layer bounds over an ``SNNSpec``.
 
         ``nonzero_tile_fracs`` is a per-weight-layer list (default: dense,
-        1.0); ``layer_kcfgs`` optionally overrides (bm, bn, bk, t_blk) per
-        weight layer — pass ``EngineLayer.kcfg`` values to price an
-        autotuned engine.  Returns per-layer :class:`LayerBound` rows plus
-        total bytes/MACs and the summed wall-time bound in seconds and
+        1.0).  Returns per-layer :class:`LayerBound` rows plus total
+        bytes/MACs and the summed wall-time bound in seconds and
         microseconds.
         """
         shapes = spec.layer_shapes()
         timesteps = spec.timesteps if timesteps is None else timesteps
         if nonzero_tile_fracs is None:
             nonzero_tile_fracs = [1.0] * len(shapes)
-        if layer_kcfgs is None:
-            layer_kcfgs = [None] * len(shapes)
         layers = []
-        for sh, frac, kcfg in zip(shapes, nonzero_tile_fracs, layer_kcfgs):
+        for sh, frac in zip(shapes, nonzero_tile_fracs):
             rows = batch * sh.out_positions if sh.kind == "conv" else batch
-            blk, tb = block, t_block
-            if kcfg is not None:
-                blk, tb = tuple(kcfg[:3]), kcfg[3]
             layers.append(self.layer_bound(
                 rows, sh.fan_in, sh.out_channels,
-                timesteps=timesteps, t_block=tb,
-                nonzero_tile_frac=frac, block=blk,
+                timesteps=timesteps, nonzero_tile_frac=frac, block=block,
             ))
         bound_s = sum(lb.bound_s for lb in layers)
         return {

@@ -127,13 +127,13 @@ def deploy(
     spec: SNNSpec,
     cfg: Optional[EngineConfig] = None,
     n_cores: int = 1,
-    device_parallel: Optional[bool] = None,
 ) -> SNNEngine:
     """Build an executable integer engine from an exported network.
 
     ``n_cores > 1`` compiles the network across a SpiDR core grid
-    (``compiler.compile_network`` -> ``engine.compile_engine``); the result
-    is bit-exact with single-core execution under any chunking.  ``cfg``
+    (``compiler.compile_network`` -> ``engine.compile_engine``), whose
+    plan prices per-core cycles and energy; outputs are the one-core
+    engine's.  ``cfg``
     defaults to the pure-jnp backend at the exported precision.
     """
     spec.require_chain("snn.export.deploy")
@@ -169,8 +169,7 @@ def deploy(
     engine = SNNEngine(spec=spec, cfg=cfg, layers=tuple(layers))
     if n_cores > 1:
         schedule = compile_network(spec, n_cores=n_cores, qspec=cfg.qspec)
-        engine = compile_engine(engine, schedule,
-                                device_parallel=device_parallel)
+        engine = compile_engine(engine, schedule)
     return engine
 
 

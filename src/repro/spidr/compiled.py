@@ -17,9 +17,10 @@ Two input forms, matching the two legacy build chains bit-for-bit:
     QAT training graph.
 
 ``target.n_cores > 1`` additionally routes through
-``compiler.compile_network`` + ``engine.compile_engine``; the compiled
-plan is bit-exact with single-core execution under any chunking, so every
-:class:`CompiledSNN` method behaves identically at any core count.
+``compiler.compile_network`` + ``engine.compile_engine``; the plan prices
+the modelled chip's per-core cycles and energy, and execution stays the
+one-core walk, so every :class:`CompiledSNN` method returns the same
+spikes and readouts at any core count.
 """
 from __future__ import annotations
 
@@ -111,64 +112,7 @@ def _engine_config(target: DeployTarget) -> EngineConfig:
         interpret=bool(interpret),
         skip_empty=target.skip_empty,
         block=tuple(target.block),
-        t_block=target.t_block,
     )
-
-
-def _autotune_engine(base: SNNEngine, spec: SNNSpec, target: DeployTarget,
-                     cfg: EngineConfig) -> SNNEngine:
-    """Bake measured per-layer kernel configs into ``base``.
-
-    Consults :func:`repro.kernels.autotune.autotune_layer` per weight
-    layer (cached by shape+precision, optionally persisted via
-    ``$SPIDR_AUTOTUNE_CACHE``) and attaches the winner as
-    ``EngineLayer.kcfg``.  Every candidate is bit-exact, so tuning
-    changes wall time only, never results.
-    """
-    from ..kernels.autotune import autotune_layer
-
-    tracer = obs_trace.default_tracer()
-    reg = obs_metrics.default_registry()
-    t_sweep = time.perf_counter()
-    shapes = iter(spec.layer_shapes())
-    new_layers = []
-    with tracer.span("autotune", cat="compile", network=spec.name):
-        for li, el in enumerate(base.layers):
-            if el.kind not in ("conv", "fc"):
-                new_layers.append(el)
-                continue
-            sh = next(shapes)
-            rows = sh.out_positions if el.kind == "conv" else 1
-            with tracer.span("autotune.layer", cat="compile", layer=li,
-                             kind=el.kind, rows=rows,
-                             channels=sh.out_channels):
-                winner = autotune_layer(
-                    rows, sh.fan_in, sh.out_channels,
-                    target.weight_bits, target.vmem_bits,
-                    timesteps=min(spec.timesteps, 8),
-                    sparsity=target.assumed_sparsity,
-                    interpret=cfg.interpret, skip_empty=cfg.skip_empty)
-            if reg:
-                # Info-gauge: the chosen KernelConfig rides in the labels
-                # (value is a constant 1, Prometheus "info" idiom).
-                bm, bn, bk, tb = winner.kcfg
-                reg.gauge(
-                    "spidr_autotune_kcfg_info",
-                    "Chosen per-layer kernel config (info gauge)",
-                    labels={"network": spec.name, "layer": li,
-                            "kind": el.kind, "block_m": bm, "block_n": bn,
-                            "block_k": bk, "t_block": tb}).set(1.0)
-            new_layers.append(dataclasses.replace(el, kcfg=winner.kcfg))
-    if reg:
-        reg.counter(
-            "spidr_autotune_seconds_total",
-            "Wall seconds spent in autotune sweeps").inc(
-                time.perf_counter() - t_sweep)
-        reg.counter(
-            "spidr_autotune_layers_total",
-            "Weight layers autotuned").inc(
-                sum(1 for el in base.layers if el.kind in ("conv", "fc")))
-    return dataclasses.replace(base, layers=tuple(new_layers))
 
 
 @dataclasses.dataclass(frozen=True)
@@ -583,24 +527,19 @@ class CompiledSNN:
                  nonzero_tile_fracs=None) -> dict:
         """Predicted wall-time bound for one chunk on this deployment.
 
-        Prices the compiled engine's actual tiling (per-layer autotuned
-        ``kcfg`` when present, else the target's ``block``/``t_block``)
-        through :class:`repro.roofline.PerfModel`: bytes-moved + MACs-at-
-        sparsity per weight layer, ``bound_us`` = summed max(compute,
-        memory) bound.  ``nonzero_tile_fracs`` is a per-weight-layer list
-        of nonzero spike-tile fractions (measure with
+        Prices the per-timestep kernel at the target's ``block`` through
+        :class:`repro.roofline.PerfModel`: bytes-moved + MACs-at-sparsity
+        per weight layer, ``bound_us`` = summed max(compute, memory)
+        bound.  ``nonzero_tile_fracs`` is a per-weight-layer list of
+        nonzero spike-tile fractions (measure with
         ``kernels.spike_tile_bitmap``); default prices dense spikes.
         """
         from ..roofline.analysis import PerfModel
 
-        kcfgs = [el.kcfg for el in self._base_engine.layers
-                 if el.kind in ("conv", "fc")]
-        cfg = self._base_engine.cfg
         return PerfModel().network_bound(
             self.spec, batch=batch, timesteps=timesteps,
-            t_block=cfg.t_block, block=cfg.block,
-            nonzero_tile_fracs=nonzero_tile_fracs,
-            layer_kcfgs=kcfgs)
+            block=self._base_engine.cfg.block,
+            nonzero_tile_fracs=nonzero_tile_fracs)
 
     # -- persistence -------------------------------------------------------
     def save(self, path, step: int = 0) -> None:
@@ -751,8 +690,7 @@ def _apply_schedule(base: SNNEngine, spec: SNNSpec, target: DeployTarget,
         assumed_sparsity=target.assumed_sparsity,
         force_mode=target.force_mode,
         force_stationarity=target.stationarity)
-    return compile_engine(base, schedule,
-                          device_parallel=target.device_parallel)
+    return compile_engine(base, schedule)
 
 
 def compile(network, params=None, target: Optional[DeployTarget] = None,
@@ -814,12 +752,6 @@ def compile(network, params=None, target: Optional[DeployTarget] = None,
 def _compile(network, params, target: DeployTarget,
              spec: Optional[SNNSpec]) -> CompiledSNN:
     cfg = _engine_config(target)
-
-    def tuned(engine: SNNEngine) -> SNNEngine:
-        if target.autotune and cfg.backend == "fused":
-            return _autotune_engine(engine, spec, target, cfg)
-        return engine
-
     if isinstance(network, ExportedNetwork):
         if spec is None and isinstance(params, SNNSpec):
             spec, params = params, None
@@ -834,7 +766,7 @@ def _compile(network, params, target: DeployTarget,
                 f"network was exported at {network.weight_bits}-bit — "
                 f"re-export, or deploy with DeployTarget(weight_bits="
                 f"{network.weight_bits})")
-        base = tuned(deploy(network, spec, cfg, n_cores=1))
+        base = deploy(network, spec, cfg, n_cores=1)
         exported = network
     elif isinstance(network, SNNSpec):
         spec = network
@@ -845,7 +777,7 @@ def _compile(network, params, target: DeployTarget,
                 "core.network.init_params or a snn.train fit; a trained "
                 "integer artifact deploys via compile(exported, spec, "
                 "target) instead")
-        base = build_engine(spec, params, cfg, tune=tuned)
+        base = build_engine(spec, params, cfg)
         exported = None
     else:
         raise TypeError(
@@ -920,10 +852,19 @@ def _spec_info(spec: SNNSpec) -> dict:
             "n_layers": len(spec.layers)}
 
 
+# Fields that older snapshots carry, each with the values that ran the
+# one walk this build runs: a snapshot holding one of them restores.
+_RETIRED_TARGET_FIELDS = {"t_block": (1,), "autotune": (False,),
+                          "device_parallel": (None, False)}
+
+
 def _target_from_info(d: dict) -> DeployTarget:
     """Rebuild the snapshot's :class:`DeployTarget` from its JSON form."""
     kw = dict(d)
     kw["block"] = tuple(kw["block"])
+    for name, served in _RETIRED_TARGET_FIELDS.items():
+        if name in kw and kw[name] in served:
+            del kw[name]
     try:
         return DeployTarget(**kw)
     except TypeError as e:
@@ -1039,8 +980,6 @@ def _compile_from_arrays(spec: SNNSpec, target: DeployTarget,
                 layers.append(EngineLayer(kind="adaptive_pool",
                                           target_hw=layer.target_hw))
         base = SNNEngine(spec=spec, cfg=cfg, layers=tuple(layers))
-    if target.autotune and cfg.backend == "fused":
-        base = _autotune_engine(base, spec, target, cfg)
     engine = _apply_schedule(base, spec, target, cfg)
     return CompiledSNN(spec=spec, target=target, engine=engine,
                        base_engine=base, exported=exported)

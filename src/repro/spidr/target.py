@@ -54,10 +54,10 @@ class DeployTarget:
 
     Topology
         ``n_cores`` > 1 routes the build through the multi-core compiler
-        (partition/place/schedule onto a core grid) — bit-exact with
-        single-core execution.  ``device_parallel`` forces ``shard_map``
-        over a real device mesh (None = auto when the host has the
-        devices); ``force_mode`` / ``stationarity`` pin the compiler's
+        (partition/place/schedule onto the modelled chip's core grid),
+        whose plan prices per-core cycles and energy; execution is the
+        one-core walk, whose outputs the plan's equal.
+        ``force_mode`` / ``stationarity`` pin the compiler's
         per-layer operating-mode (1/2) and weight-vs-Vmem stationarity
         choices instead of letting the cost model pick;
         ``assumed_sparsity`` feeds its load-balancing heuristics.
@@ -67,10 +67,7 @@ class DeployTarget:
         bit-exact oracle) or ``"reference"`` (unjitted python-loop oracle —
         slow, for verification).  ``interpret`` (None = auto: on unless the
         host is a TPU), ``skip_empty`` and ``block`` configure the fused
-        kernels; ``t_block`` > 1 switches them to the Vmem-stationary
-        multi-timestep tiling and ``autotune=True`` measures the fastest
-        per-layer (block, T_blk) at compile time and caches it by
-        shape+precision (``repro.kernels.autotune``).
+        kernel.
 
     Streaming
         ``stream_capacity`` slots of persistent Vmem and ``chunk_T``
@@ -88,15 +85,7 @@ class DeployTarget:
     interpret: Optional[bool] = None     # None -> auto (on unless on TPU)
     skip_empty: bool = True
     block: tuple = DEFAULT_BLOCK
-    # Vmem-stationary timestep tiling: >1 runs fused chunks layer-outer in
-    # T_blk-sized slabs (each weight block read once per slab, not once per
-    # timestep).  Bit-exact with t_block=1 for any value.
-    t_block: int = 1
-    # Measure-and-cache the fastest (block_m, block_n, block_k, T_blk) per
-    # weight layer at compile time (kernels.autotune); fused backend only.
-    autotune: bool = False
     # Multi-core compiler knobs.
-    device_parallel: Optional[bool] = None
     force_mode: Optional[int] = None     # pin operating mode 1 | 2
     stationarity: Optional[str] = None   # pin "weight" | "vmem"
     assumed_sparsity: float = 0.9
@@ -126,15 +115,6 @@ class DeployTarget:
         _require_positive_int(
             "stream_capacity", self.stream_capacity,
             hint="concurrent persistent-Vmem stream slots")
-        _require_positive_int(
-            "t_block", self.t_block,
-            hint="timesteps per Vmem-stationary kernel slab; 1 disables "
-            "tiling")
-        if self.autotune and self.backend != "fused":
-            raise ValueError(
-                f"autotune=True tunes the fused Pallas kernels but "
-                f"backend={self.backend!r} never runs them — deploy with "
-                "backend='fused' (or drop autotune)")
         if self.force_mode is not None and self.force_mode not in (1, 2):
             raise ValueError(
                 f"force_mode={self.force_mode!r} unsupported — the macro "

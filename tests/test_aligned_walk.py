@@ -21,7 +21,6 @@ from repro.core.neuron import NeuronConfig
 from repro.core.quant import QuantSpec
 from repro.engine import inference
 from repro.engine.inference import init_state, run_chunk, run_reference
-from repro.kernels import autotune
 
 _N = NeuronConfig(model="if", reset="soft", threshold=0.5)
 
@@ -157,19 +156,17 @@ def test_only_an_unaligned_width_is_padded(spec, aligned):
     assert ("spidr.align" not in text) == aligned
 
 
-@pytest.mark.parametrize("spec,t_block,pad_w", [
-    (gesture_net(), 1, 0),
-    (spidr_optflow.CONFIG, 1, 0),
-    (lif_firenet.CONFIG, 1, 6),
-    (lif_firenet.TINY, 1, 22),
-    (spidr_gesture.reduced(hw=(24, 24)), 1, 8),
-    (spidr_gesture.reduced(hw=(24, 24)), 2, 0),   # the t_block walk
-], ids=["gesture", "optflow", "firenet", "firenet_tiny", "chain_24",
-        "chain_24_tblk"])
-def test_the_build_span_reads_the_columns_added(spec, t_block, pad_w):
+@pytest.mark.parametrize("spec,pad_w", [
+    (gesture_net(), 0),
+    (spidr_optflow.CONFIG, 0),
+    (lif_firenet.CONFIG, 6),
+    (lif_firenet.TINY, 22),
+    (spidr_gesture.reduced(hw=(24, 24)), 8),
+], ids=["gesture", "optflow", "firenet", "firenet_tiny", "chain_24"])
+def test_the_build_span_reads_the_columns_added(spec, pad_w):
     builds = _build_pads(lambda: inference.build_engine(
         spec, init_params(jax.random.PRNGKey(0), spec),
-        inference.EngineConfig(QuantSpec(4), t_block=t_block)))
+        inference.EngineConfig(QuantSpec(4))))
     assert builds == [pad_w]
 
 
@@ -186,22 +183,16 @@ def _build_pads(build):
             if e["name"] == "engine.build"]
 
 
-@pytest.mark.parametrize("t_block,pad_w", [(1, 8), (2, 0)],
-                         ids=["scan", "tblk"])
-def test_the_autotuned_engine_decides_the_pad(monkeypatch, t_block, pad_w):
-    """Autotune may hand a layer a ``t_block`` over 1, which sends every
-    chunk to the unpadded tiled walk: the span reads the pad of the engine
-    ``spidr.compile`` returns, and its chunk step pads just as the span
-    reads."""
-    monkeypatch.setattr(autotune, "autotune_layer", lambda *a, **k:
-                        autotune.KernelConfig(t_block=t_block))
+@pytest.mark.parametrize("n_cores", [1, 4])
+def test_the_compiled_engine_decides_the_pad(n_cores):
+    """The engine ``spidr.compile`` returns, on one core or with the
+    modelled chip's four-core plan attached, pads its chunk step just as
+    its build span reads: the plan does not change the walk."""
     spec = spidr_gesture.reduced(hw=(24, 24), timesteps=2)
     params = init_params(jax.random.PRNGKey(0), spec)
     compiled = []
     builds = _build_pads(lambda: compiled.append(spidr.compile(
-        spec, params, spidr.DeployTarget(backend="fused", autotune=True))))
-    assert builds == [pad_w]
-    engine = compiled[0].engine
-    assert {el.kcfg[3] for el in engine.layers
-            if el.kind in ("conv", "fc")} == {t_block}
-    assert ("spidr.align" in _chunk_step_text(engine)) == bool(pad_w)
+        spec, params, spidr.DeployTarget(backend="fused",
+                                         n_cores=n_cores))))
+    assert builds == [8]
+    assert "spidr.align" in _chunk_step_text(compiled[0].engine)

@@ -259,7 +259,7 @@ class TestMulticoreExecution:
                                       np.asarray(b.spike_counts))
 
     def test_fused_backend_multicore(self):
-        """The Pallas fused kernel vmaps over the cores axis (interpret)."""
+        """A 2-core plan on the Pallas fused backend (interpret)."""
         spec = spidr_gesture.reduced(hw=(16, 16), timesteps=2)
         params = init_params(jax.random.PRNGKey(0), spec)
         qspec = QuantSpec(4)
@@ -278,30 +278,49 @@ class TestMulticoreExecution:
         with pytest.raises(AssertionError):
             compile_engine(meng, schedule)
 
-    def test_shard_map_device_parallel(self):
-        """Real device parallelism over the cores mesh axis: 4 forced host
-        devices, outputs bit-exact with single-core, in a subprocess so
-        the device count doesn't leak into this process's jax."""
-        code = """
+    @pytest.mark.parametrize("backend", ["jnp", "fused"])
+    def test_four_cores_run_one_walk(self, backend):
+        """On a host with 4 devices (forced, in a subprocess so the device
+        count doesn't leak into this process's jax), a 4-core target's
+        chunk step lowers to the 1-core program; its readouts and counts
+        are one core's, and a stream's per-core cycles are
+        ``estimate_multicore_cost``'s for its counts."""
+        code = f"""
 import numpy as np, jax, jax.numpy as jnp
+from repro import spidr
 from repro.configs import spidr_gesture
 from repro.core.network import init_params
-from repro.core.quant import QuantSpec
-from repro.engine import EngineConfig, build_engine, compile_engine, run_engine
-from repro.compiler import compile_network
+from repro.engine import estimate_multicore_cost, init_state, run_chunk
 assert len(jax.devices()) == 4
-spec = spidr_gesture.reduced(hw=(16, 16), timesteps=3)
+spec = spidr_gesture.reduced(hw=(16, 16), timesteps=4)
 params = init_params(jax.random.PRNGKey(0), spec)
-eng = build_engine(spec, params, EngineConfig(QuantSpec(4), backend="jnp"))
-meng = compile_engine(eng, compile_network(spec, n_cores=4,
-                                           qspec=QuantSpec(4)))
-assert meng.device_parallel
+one, four = (spidr.compile(spec, params, spidr.DeployTarget(
+    backend="{backend}", n_cores=n)) for n in (1, 4))
+assert one.schedule is None and four.schedule.n_cores == 4
 rng = np.random.default_rng(0)
-ev = jnp.asarray((rng.random((3, 2, 16, 16, 2)) > 0.9).astype(np.float32))
-a, b = run_engine(eng, ev), run_engine(meng, ev)
-assert (np.asarray(a.readout) == np.asarray(b.readout)).all()
-assert (np.asarray(a.spike_counts) == np.asarray(b.spike_counts)).all()
-print("SHARD_MAP_OK")
+ev = jnp.asarray((rng.random((4, 2, 16, 16, 2)) > 0.9).astype(np.float32))
+
+def step_text(compiled):
+    engine = compiled.engine
+    return jax.jit(lambda st, e: run_chunk(engine, st, e)).lower(
+        init_state(engine, 2), ev).as_text()
+
+assert step_text(four) == step_text(one)
+a, b = one.run(ev), four.run(ev)
+for f in ("readout", "spike_counts", "input_counts"):
+    assert (np.asarray(getattr(a, f)) == np.asarray(getattr(b, f))).all(), f
+sess = four.open_stream(capacity=2, chunk_T=2)
+slot = sess.open()
+for t0 in (0, 2):
+    up = sess.step({{slot: np.asarray(ev)[t0:t0 + 2, 0]}})[slot]
+mc = estimate_multicore_cost(spec, four.schedule,
+                             np.asarray(one.run(ev[:, :1]).input_counts))
+want = np.array([pc.makespan_cycles for pc in mc.per_core]) \\
+    + mc.routing_cycles
+assert (np.asarray(up.per_core_cycles) == want).all()
+assert up.cycles == int(want.max())
+np.testing.assert_array_equal(up.readout, np.asarray(a.readout)[0])
+print("ONE_WALK_OK")
 """
         import os
 
@@ -312,7 +331,7 @@ print("SHARD_MAP_OK")
         res = subprocess.run([sys.executable, "-c", code], env=env,
                              capture_output=True, text=True, timeout=560)
         assert res.returncode == 0, res.stderr[-2000:]
-        assert "SHARD_MAP_OK" in res.stdout
+        assert "ONE_WALK_OK" in res.stdout
 
 
 # ---------------------------------------------------------------------------

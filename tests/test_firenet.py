@@ -271,12 +271,6 @@ def test_a_chain_keeps_no_previous_spikes(spec):
 # ---------------------------------------------------------------------------
 # What runs chains only refuses, naming the layer.
 # ---------------------------------------------------------------------------
-def test_the_t_block_walk_refuses_a_recurrent_layer():
-    compiled = _compiled("fused", t_block=2)
-    with pytest.raises(ValueError, match=r"t_block .* layer 1 is recurrent"):
-        compiled.run(jnp.asarray(_clip()[:, None]))
-
-
 def test_the_multi_core_path_refuses_a_recurrent_layer():
     with pytest.raises(ValueError, match=r"n_cores > 1.* layer 1 is "
                                          r"recurrent"):

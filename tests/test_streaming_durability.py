@@ -282,6 +282,38 @@ class TestSnapshotRestoreMigration:
         with pytest.raises(ValueError, match="identical"):
             spidr.restore(str(tmp_path), compiled=other)
 
+    # Target fields an older build wrote into its snapshots: the values
+    # that ran the one walk this build runs restore, any other is refused.
+    @pytest.mark.parametrize("fields,restores", [
+        ({"t_block": 1, "autotune": False, "device_parallel": None}, True),
+        ({"t_block": 1, "autotune": False, "device_parallel": False}, True),
+        ({"t_block": 2, "autotune": False, "device_parallel": None}, False),
+        ({"t_block": 1, "autotune": True, "device_parallel": None}, False),
+        ({"t_block": 1, "autotune": False, "device_parallel": True}, False),
+    ], ids=["defaults", "one_device", "t_block_2", "autotune",
+            "device_parallel"])
+    def test_an_older_builds_target_fields(self, tmp_path, fields,
+                                           restores):
+        compiled = _compiled()
+        sess = compiled.open_stream(2, 2)
+        s0 = sess.open()
+        rng = np.random.default_rng(17)
+        sess.step({s0: _chunk(rng, 2)})
+        compiled.snapshot(str(tmp_path), sessions=[sess])
+        meta_path = tmp_path / "step_000000000" / "meta.json"
+        meta = json.loads(meta_path.read_text())
+        meta["spidr_session_snapshot"]["target"].update(fields)
+        meta_path.write_text(json.dumps(meta))
+        if not restores:
+            with pytest.raises(ValueError, match="re-snapshot"):
+                spidr.restore(str(tmp_path))
+            return
+        restored = spidr.restore(str(tmp_path))
+        assert restored.target == compiled.target
+        later = {s0: _chunk(rng, 2)}
+        assert _update_key(sess.step(later)[s0]) \
+            == _update_key(restored.sessions[0].step(later)[s0])
+
     def test_non_snapshot_checkpoint_is_refused(self, tmp_path):
         from repro.checkpoint.checkpoint import Checkpointer
 

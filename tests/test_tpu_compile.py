@@ -27,11 +27,7 @@ from repro import spidr
 from repro.configs import lif_firenet
 from repro.core.network import init_params
 from repro.engine.inference import init_state, run_chunk
-from repro.kernels.autotune import _default_candidates
-from repro.kernels.fused_lif_gemm import (
-    fused_lif_gemm_int,
-    fused_lif_gemm_int_tblk,
-)
+from repro.kernels.fused_lif_gemm import fused_lif_gemm_int
 from repro.runtime import compile_cache
 
 # (M, F, K): GEMM rows, fan-in, output channels.
@@ -135,36 +131,23 @@ def test_firenet_chunk_step_has_no_relayout_loops(one_chip):
     assert total <= 13.5e9, total
 
 
-@pytest.mark.parametrize("width", sorted(WIDTHS))
-@pytest.mark.parametrize("per_channel", [False, True],
-                         ids=["scalar_thr", "channel_thr"])
-def test_tblk_compiles(one_chip, width, per_channel):
-    m, f, k = WIDTHS[width]
-    args = [_sds(one_chip, (4, m, f), jnp.int8),
+# The blocks of {32, 128}^3 that the lane rule admits at gesture conv2's
+# widths (F = 144, K = 16): ``bk`` must be 128, ``bn`` may cover the 16
+# channels in one block of 32 or be 128.
+GESTURE_BLOCKS = [(bm, bn, 128) for bm in (32, 128) for bn in (32, 128)]
+
+
+@pytest.mark.parametrize("block", GESTURE_BLOCKS,
+                         ids=["x".join(map(str, b)) for b in GESTURE_BLOCKS])
+def test_fused_int_compiles_at_every_legal_block(one_chip, block):
+    """The chip's compiler accepts every tiling the lane rule admits."""
+    m, f, k = WIDTHS["gesture_conv2_b4"]
+    args = [_sds(one_chip, (m, f), jnp.int8),
             _sds(one_chip, (f, k), jnp.int8),
             _sds(one_chip, (m, k), jnp.int32)]
-    if per_channel:
-        args.append(_sds(one_chip, (k,), jnp.int32))
-        fn = lambda s, w, v, t: fused_lif_gemm_int_tblk(s, w, v, threshold=t,
-                                                        soft_reset=True)
-    else:
-        fn = lambda s, w, v: fused_lif_gemm_int_tblk(s, w, v, threshold=3)
-    _assert_kernel_compiles(fn, *args)
-
-
-def test_every_autotune_candidate_compiles(one_chip):
-    """The tuner never offers a tiling the chip's compiler refuses."""
-    m, f, k = WIDTHS["gesture_conv2_b4"]
-    t = 4
-    cands = _default_candidates(m, f, k, timesteps=t)
-    assert cands
-    for cand in cands:
-        args = [_sds(one_chip, (cand.t_block, m, f), jnp.int8),
-                _sds(one_chip, (f, k), jnp.int8),
-                _sds(one_chip, (m, k), jnp.int32)]
-        _assert_kernel_compiles(
-            lambda s, w, v, cand=cand: fused_lif_gemm_int_tblk(
-                s, w, v, threshold=3, block=cand.block), *args)
+    _assert_kernel_compiles(
+        lambda s, w, v: fused_lif_gemm_int(s, w, v, threshold=3,
+                                           block=block), *args)
 
 
 @pytest.fixture

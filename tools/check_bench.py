@@ -22,7 +22,7 @@ compares a freshly generated file against the committed
     tolerance is deliberately loose (3.0 = 4x the committed ratio):
     interpret-mode wall clock jitters across runners, and the gate exists
     to catch order-of-magnitude schedule regressions (a dropped
-    block-skip, a retraced jit, a T_blk tiling that stopped engaging).
+    block-skip, a retraced jit).
 
 Improvements (fewer cycles, less energy, better metric) always pass, with
 a note suggesting a baseline refresh so the gate tightens over time.

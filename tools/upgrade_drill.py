@@ -122,7 +122,7 @@ def child_serve(cfg: dict, seed: int, snap_dir: str, die_at: int) -> None:
     from repro import obs
     from repro.serving import StreamWorker
 
-    # Trace the whole doomed run: compile/autotune spans plus every
+    # Trace the whole doomed run: compile spans plus every
     # serve.tick/run_chunk up to the fatal tick.  The trace is exported
     # from the mid-tick hook — synchronously, before the SIGKILL lands —
     # so the parent can embed the kill-tick span timeline in its report.
